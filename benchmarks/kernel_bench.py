@@ -32,7 +32,7 @@ from repro.autotune import cache as tuning
 from repro.core import transform_chain as tc
 from repro.core import transform_engine as te
 from repro.kernels import dispatch
-from repro.roofline import HBM_BW
+from repro.roofline import peaks
 
 
 def _cfg_tag(kernel: str, dtype: str, n: int) -> str:
@@ -119,7 +119,7 @@ def run(smoke: bool = False) -> list[str]:
 
     vecadd = jax.jit(lambda a, b: kernels.vecadd(a, b))
     us = _time(vecadd, x, z, iters=iters)
-    tpu_us = 3 * x.size * 4 / HBM_BW * 1e6
+    tpu_us = 3 * x.size * 4 / peaks("TPU v5 lite").hbm_bw * 1e6
     rows.append(f"kernel_vecadd_translation{tag},{us:.1f},"
                 f"elems_per_us={x.size/us:.0f};tpu_projection_us={tpu_us:.1f}")
 

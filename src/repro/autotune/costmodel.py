@@ -42,7 +42,7 @@ STEP_OVERHEAD_US = 0.02
 #: effective streaming bandwidth for the predicted-time denominator.  The
 #: empirical timer runs wherever it runs; the model only needs candidate
 #: ORDERING to be right, so one conservative CPU-class figure is used for
-#: every backend (the TPU projection in benchmarks uses roofline.HBM_BW).
+#: every backend (the TPU projection in benchmarks uses roofline.PEAKS).
 MODEL_BW = 20e9
 #: VMEM feasibility budget per core (v5e-class); candidates whose working
 #: set exceeds this are rejected before timing.

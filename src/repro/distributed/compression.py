@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import shard_map
-
 
 def quantize_int8(g: jnp.ndarray, err: jnp.ndarray):
     """(g + err) -> (int8 q, fp32 scale, new_err)."""
@@ -57,9 +55,9 @@ def pod_sync_int8(grads, err_state, mesh, pspecs):
 
         inner_spec = P(*(s if s != "pod" else None for s in
                          (spec or P(*(None,) * g.ndim))))
-        fn = shard_map(inner, mesh=mesh,
-                       in_specs=(inner_spec, inner_spec),
-                       out_specs=(inner_spec, inner_spec))
+        fn = jax.shard_map(inner, mesh=mesh,
+                           in_specs=(inner_spec, inner_spec),
+                           out_specs=(inner_spec, inner_spec))
         return fn(g, err)
 
     flat_g, treedef = jax.tree_util.tree_flatten(grads)

@@ -28,30 +28,11 @@ if TYPE_CHECKING:  # avoid repro.models import cycle (models use constrain())
 
 
 def ambient_mesh():
-    """Version-portable ``jax.sharding.get_abstract_mesh()``: older jax
-    exposes the ambient mesh only as the thread-local physical mesh set by
-    the ``with mesh:`` context.  Returns None when no mesh is active."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:                      # pragma: no cover - jax internals
-        return None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``jax.shard_map``: older jax ships it under
-    ``jax.experimental.shard_map`` with ``check_rep`` instead of
-    ``check_vma`` (same replication-checking knob, renamed)."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    """The abstract mesh of the enclosing ``jax.set_mesh`` scope (usable
+    inside ``jit``), or None when no mesh is set.  Placing arrays needs
+    the concrete mesh: ``jax.sharding.get_mesh()``, outside ``jit``."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def axis_names(mesh: Mesh) -> tuple[tuple[str, ...], str]:

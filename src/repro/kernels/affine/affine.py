@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import (LANES, SUBLANES, pad2d, pad_axis, pick_block,
-                                stage_flat, stage_packed)
+from repro.kernels.util import (LANES, SUBLANES, for_lane_chunks, pad2d,
+                                pad_axis, pick_block, stage_flat, stage_packed)
 
 
 def _affine_kernel(x_ref, s_ref, t_ref, o_ref):
@@ -117,12 +117,11 @@ def chain_diag_1d(flat: jnp.ndarray, s: jnp.ndarray, t: jnp.ndarray,
 
 
 def _chain_diag_batch_kernel(x_ref, s_ref, t_ref, o_ref, *, g: int):
-    x = x_ref[...]                                   # (bm, wr) -- bm requests
-    bm, wr = x.shape
-    x3 = x.reshape(bm, wr // g, g)
-    s = s_ref[...][:, None, :]                       # per-request params,
-    t = t_ref[...][:, None, :]                       # row-aligned with x
-    o_ref[...] = (x3 * s + t).reshape(bm, wr)
+    def chunk(lanes):
+        # a (bm, g) chunk of bm requests meets their row-aligned params
+        o_ref[:, lanes] = x_ref[:, lanes] * s_ref[...] + t_ref[...]
+
+    for_lane_chunks(x_ref.shape[1], g, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))

@@ -22,23 +22,26 @@ import jax
 _BACKEND: str = "auto"
 _VALID = ("auto", "pallas", "interpret", "ref")
 
-#: the degradation ladder, fastest substrate first: a launch that keeps
-#: failing on one rung falls to the next -- ``pallas`` (compiled TPU
-#: kernel) degrades to ``interpret`` (same kernel body, Pallas
-#: interpreter: survives Mosaic/compile faults), which degrades to
-#: ``ref`` (the pure-jnp oracle: survives kernel-body faults).  Every
-#: rung computes the same function (the paper's context-word
+#: the degradation ladders, fastest substrate first: a launch that keeps
+#: failing on one rung falls to the next.  ``interpret`` (the kernel body
+#: in the Pallas interpreter) degrades to ``ref`` (the pure-jnp oracle:
+#: survives kernel-body faults).  ``pallas`` (compiled TPU kernel)
+#: degrades straight to ``ref``: the interpreter is a CPU-correctness
+#: tool, and a request served by it on a chip would hide a kernel the
+#: chip's compiler refused behind a result thousands of times slower.
+#: Every rung computes the same function (the paper's context-word
 #: discipline), so degrading trades speed, never results.
-FALLBACK_ORDER = ("pallas", "interpret", "ref")
+_LADDERS = {"pallas": ("pallas", "ref"), "interpret": ("interpret", "ref"),
+            "ref": ("ref",)}
 
 
 def fallback_ladder(backend: str | None = None) -> tuple[str, ...]:
     """The rungs a failing launch may degrade through, starting at (and
-    including) the resolved ``backend``: ``("interpret", "ref")`` for an
-    interpret server, just ``("ref",)`` at the bottom.  The serving
-    engine walks this per failing bucket (see ``serving.engine``)."""
-    b = resolve(backend)
-    return FALLBACK_ORDER[FALLBACK_ORDER.index(b):]
+    including) the resolved ``backend``: ``("pallas", "ref")`` for a
+    pallas server, ``("interpret", "ref")`` for an interpret one, just
+    ``("ref",)`` at the bottom.  The serving engine walks this per
+    failing bucket (see ``serving.engine``)."""
+    return _LADDERS[resolve(backend)]
 
 
 def set_backend(name: str) -> None:

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.matmul.matmul import _coef_rows
-from repro.kernels.util import SUBLANES, pad_axis, stage_flat, stage_packed
+from repro.kernels.util import (SUBLANES, for_lane_chunks, lane_shift,
+                                pad_axis, stage_flat, stage_packed)
 
 
 def _requant_store(acc, n_frac: int):
@@ -88,7 +89,7 @@ def _chain_matrix_q_kernel(x_ref, c_ref, t_ref, o_ref, *, d: int,
     c = c_ref[...].astype(jnp.int32)
     acc = jnp.zeros_like(x) + (t_ref[...].astype(jnp.int32) << n_frac)
     for i, delta in enumerate(range(-(d - 1), d)):
-        acc = acc + jnp.roll(x, -delta, axis=1) * c[i:i + 1, :]
+        acc = acc + lane_shift(x, delta) * c[i:i + 1, :]
     o_ref[...] = _requant_store(acc, n_frac)
 
 
@@ -127,12 +128,14 @@ def chain_matrix_1d_q(flat: jnp.ndarray, a: jnp.ndarray, t: jnp.ndarray,
 
 def _chain_diag_batch_q_kernel(x_ref, s_ref, t_ref, o_ref, *, g: int,
                                n_frac: int):
-    x = x_ref[...].astype(jnp.int32)                 # (bm, wr) -- bm requests
-    bm, wr = x.shape
-    x3 = x.reshape(bm, wr // g, g)
-    s = s_ref[...].astype(jnp.int32)[:, None, :]     # per-request params,
-    t = (t_ref[...].astype(jnp.int32) << n_frac)[:, None, :]
-    o_ref[...] = _requant_store((x3 * s + t).reshape(bm, wr), n_frac)
+    def chunk(lanes):
+        # a (bm, g) chunk of bm requests meets their row-aligned params
+        x = x_ref[:, lanes].astype(jnp.int32)
+        s = s_ref[...].astype(jnp.int32)
+        t = t_ref[...].astype(jnp.int32) << n_frac
+        o_ref[:, lanes] = _requant_store(x * s + t, n_frac)
+
+    for_lane_chunks(x_ref.shape[1], g, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("n_frac", "interpret",
@@ -166,16 +169,15 @@ def chain_diag_batch_2d_q(pts3: jnp.ndarray, s: jnp.ndarray, t: jnp.ndarray,
 
 def _chain_matrix_batch_q_kernel(x_ref, c_ref, t_ref, o_ref, *, d: int,
                                  g: int, n_frac: int):
-    x = x_ref[...].astype(jnp.int32)                 # (bm, wr) -- bm requests
-    bm, wr = x.shape
-    reps = wr // g
-    t = (t_ref[...].astype(jnp.int32) << n_frac)[:, None, :]
-    acc = jnp.zeros_like(x).reshape(bm, reps, g) + t
-    c = c_ref[...].astype(jnp.int32)
-    for i, delta in enumerate(range(-(d - 1), d)):
-        xr = jnp.roll(x, -delta, axis=1).reshape(bm, reps, g)
-        acc = acc + xr * c[:, i * g:(i + 1) * g][:, None, :]
-    o_ref[...] = _requant_store(acc.reshape(bm, wr), n_frac)
+    def chunk(lanes):
+        x = x_ref[:, lanes].astype(jnp.int32)        # (bm, g) of bm requests
+        acc = jnp.zeros_like(x) + (t_ref[...].astype(jnp.int32) << n_frac)
+        for i, delta in enumerate(range(-(d - 1), d)):
+            c = c_ref[:, i * g:(i + 1) * g].astype(jnp.int32)
+            acc = acc + lane_shift(x, delta) * c
+        o_ref[:, lanes] = _requant_store(acc, n_frac)
+
+    for_lane_chunks(x_ref.shape[1], g, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("n_frac", "interpret",

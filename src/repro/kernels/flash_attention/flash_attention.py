@@ -22,8 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import (SUBLANES, CompilerParams, pad_axis,
-                               pick_block)
+from repro.kernels.util import SUBLANES, pad_axis, pick_block
 
 _NEG_INF = -1e30
 
@@ -123,7 +122,7 @@ def flash_attention_3d(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 128), jnp.float32),   # l (running denominator)
             pltpu.VMEM((bq, d), jnp.float32),     # acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

@@ -18,8 +18,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.util import (LANES, SUBLANES, CompilerParams, pad_axis,
-                                pick_block, stage_flat, stage_packed)
+from repro.kernels.util import (LANES, SUBLANES, for_lane_chunks, lane_shift,
+                                pad_axis, pick_block, stage_flat,
+                                stage_packed)
 
 
 def _matmul_kernel(x_ref, y_ref, o_ref, acc_ref, *, nk: int):
@@ -63,7 +64,7 @@ def matmul_2d(x: jnp.ndarray, y: jnp.ndarray, *, bm: int = 128, bn: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, yp)
@@ -90,7 +91,7 @@ def _chain_matrix_kernel(x_ref, c_ref, t_ref, o_ref, *, d: int):
     c = c_ref[...]
     acc = jnp.zeros_like(x) + t_ref[...]
     for i, delta in enumerate(range(-(d - 1), d)):
-        acc = acc + jnp.roll(x, -delta, axis=1) * c[i:i + 1, :]
+        acc = acc + lane_shift(x, delta) * c[i:i + 1, :]
     o_ref[...] = acc
 
 
@@ -144,14 +145,14 @@ def _coef_rows(a: jnp.ndarray, lane_coord: jnp.ndarray, d: int) -> jnp.ndarray:
 
 
 def _chain_matrix_batch_kernel(x_ref, c_ref, t_ref, o_ref, *, d: int, g: int):
-    x = x_ref[...]                                   # (bm, wr) -- bm requests
-    bm, wr = x.shape
-    reps = wr // g
-    acc = jnp.zeros_like(x).reshape(bm, reps, g) + t_ref[...][:, None, :]
-    for i, delta in enumerate(range(-(d - 1), d)):
-        xr = jnp.roll(x, -delta, axis=1).reshape(bm, reps, g)
-        acc = acc + xr * c_ref[...][:, i * g:(i + 1) * g][:, None, :]
-    o_ref[...] = acc.reshape(bm, wr)
+    def chunk(lanes):
+        x = x_ref[:, lanes]                          # (bm, g) of bm requests
+        acc = jnp.zeros_like(x) + t_ref[...]
+        for i, delta in enumerate(range(-(d - 1), d)):
+            acc = acc + lane_shift(x, delta) * c_ref[:, i * g:(i + 1) * g]
+        o_ref[:, lanes] = acc
+
+    for_lane_chunks(x_ref.shape[1], g, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -163,10 +164,11 @@ def chain_matrix_batch_2d(pts3: jnp.ndarray, a: jnp.ndarray, t: jnp.ndarray,
     ``pts3`` is a packed (B, L, d) batch (one serving request per row,
     padded to a common L); ``a`` (B, d, d) / ``t`` (B, d) are per-request
     folded parameters.  Same 2d-1 lane-rolled MAC schedule as
-    ``chain_matrix_1d`` -- rolls stay inside a block row, so they never
-    mix requests, and wrapped lanes always meet a zero coefficient -- but
-    the coefficient rows are *row-aligned* (request b's block row meets
-    request b's coefficients), making a whole plan bucket one launch.
+    ``chain_matrix_1d`` -- rolls stay inside one g-lane chunk of a block
+    row, so they never mix requests, and wrapped lanes always meet a zero
+    coefficient -- but the coefficient rows are *row-aligned* (request b's
+    block row meets request b's coefficients), making a whole plan bucket
+    one launch.
     ``block_rows`` pins the batch-axis block (the autotuner's knob;
     ``None`` = VMEM-budget heuristic).
     """

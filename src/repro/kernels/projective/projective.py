@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import SUBLANES, pad_axis, stage_flat, stage_packed
+from repro.kernels.util import (SUBLANES, for_lane_chunks, lane_shift,
+                                pad_axis, stage_flat, stage_packed)
 
 
 def _proj_rows(h: jnp.ndarray, lane_coord: jnp.ndarray, d: int):
@@ -62,7 +63,7 @@ def _chain_project_kernel(x_ref, c_ref, wc_ref, g_ref, p_ref, o_ref, m_ref,
     acc = jnp.zeros_like(x) + p[0:1, :]
     wacc = jnp.zeros_like(x) + p[1:2, :]
     for i, delta in enumerate(range(-(d - 1), d)):
-        xr = jnp.roll(x, -delta, axis=1)
+        xr = lane_shift(x, delta)
         acc = acc + xr * c_ref[i:i + 1, :]
         wacc = wacc + xr * wc_ref[i:i + 1, :]
     w_ok = wacc > 0.0
@@ -72,7 +73,7 @@ def _chain_project_kernel(x_ref, c_ref, wc_ref, g_ref, p_ref, o_ref, m_ref,
     mask = jnp.ones_like(x)
     for i, delta in enumerate(range(-(d - 1), d)):
         g = g_ref[i:i + 1, :]
-        mask = mask * (jnp.roll(inl, -delta, axis=1) * g + (1.0 - g))
+        mask = mask * (lane_shift(inl, delta) * g + (1.0 - g))
     o_ref[...] = v
     m_ref[...] = mask
 
@@ -123,29 +124,27 @@ def chain_project_1d(flat: jnp.ndarray, h: jnp.ndarray, lo: jnp.ndarray,
 
 def _chain_project_batch_kernel(x_ref, c_ref, wc_ref, g_ref, p_ref, o_ref,
                                 m_ref, *, d: int, g: int):
-    x = x_ref[...]                                   # (bm, wr) -- bm requests
-    bm, wr = x.shape
-    reps = wr // g
-    p = p_ref[...]                                   # (bm, 4g): t, wt, lo, hi
-    acc = jnp.zeros_like(x).reshape(bm, reps, g) + p[:, None, 0:g]
-    wacc = jnp.zeros_like(x).reshape(bm, reps, g) + p[:, None, g:2 * g]
-    for i, delta in enumerate(range(-(d - 1), d)):
-        xr = jnp.roll(x, -delta, axis=1).reshape(bm, reps, g)
-        acc = acc + xr * c_ref[...][:, None, i * g:(i + 1) * g]
-        wacc = wacc + xr * wc_ref[...][:, None, i * g:(i + 1) * g]
-    w_ok = wacc > 0.0
-    v = acc / jnp.where(w_ok, wacc, jnp.ones_like(wacc))
-    inl = jnp.where(w_ok & (v >= p[:, None, 2 * g:3 * g])
-                    & (v <= p[:, None, 3 * g:4 * g]),
-                    jnp.ones_like(v), jnp.zeros_like(v))
-    inl2 = inl.reshape(bm, wr)
-    mask = jnp.ones_like(inl)
-    for i, delta in enumerate(range(-(d - 1), d)):
-        gm = g_ref[...][0:1, None, i * g:(i + 1) * g]
-        mask = mask * (jnp.roll(inl2, -delta, axis=1).reshape(bm, reps, g)
-                       * gm + (1.0 - gm))
-    o_ref[...] = v.reshape(bm, wr)
-    m_ref[...] = mask.reshape(bm, wr)
+    def chunk(lanes):                   # p_ref rows (bm, 4g): t, wt, lo, hi
+        x = x_ref[:, lanes]                          # (bm, g) of bm requests
+        acc = jnp.zeros_like(x) + p_ref[:, 0:g]
+        wacc = jnp.zeros_like(x) + p_ref[:, g:2 * g]
+        for i, delta in enumerate(range(-(d - 1), d)):
+            xr = lane_shift(x, delta)
+            acc = acc + xr * c_ref[:, i * g:(i + 1) * g]
+            wacc = wacc + xr * wc_ref[:, i * g:(i + 1) * g]
+        w_ok = wacc > 0.0
+        v = acc / jnp.where(w_ok, wacc, jnp.ones_like(wacc))
+        inl = jnp.where(w_ok & (v >= p_ref[:, 2 * g:3 * g])
+                        & (v <= p_ref[:, 3 * g:4 * g]),
+                        jnp.ones_like(v), jnp.zeros_like(v))
+        mask = jnp.ones_like(inl)
+        for i, delta in enumerate(range(-(d - 1), d)):
+            gm = g_ref[:, i * g:(i + 1) * g]
+            mask = mask * (lane_shift(inl, delta) * gm + (1.0 - gm))
+        o_ref[:, lanes] = v
+        m_ref[:, lanes] = mask
+
+    for_lane_chunks(x_ref.shape[1], g, chunk)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -158,9 +157,10 @@ def chain_project_batch_2d(pts3: jnp.ndarray, h: jnp.ndarray,
     ``pts3`` is a packed (B, L, d) batch (one serving request per row,
     padded to a common L); ``h`` (B, d+1, d+1) / ``lo``/``hi`` (B, d) are
     per-request folded parameters.  Same rolled MAC + divide + mask
-    schedule as ``chain_project_1d`` -- rolls stay inside a block row, so
-    they never mix requests -- but every coefficient/bounds row is
-    *row-aligned* (request b's block row meets request b's parameters).
+    schedule as ``chain_project_1d`` -- rolls stay inside one g-lane chunk
+    of a block row, so they never mix requests -- but every
+    coefficient/bounds row is *row-aligned* (request b's block row meets
+    request b's parameters).
     Returns the projected (B, L, d) batch and a (B, L) float mask.
     ``block_rows`` pins the batch-axis block (``None`` = VMEM heuristic).
     """

@@ -9,15 +9,40 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 SUBLANES = 8
 
-#: version-portable Pallas-TPU compiler params (renamed across jax versions)
-CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+
+def lane_shift(x: jnp.ndarray, delta: int) -> jnp.ndarray:
+    """``out[:, j] = x[:, (j + delta) mod w]`` along the lane axis -- the
+    rolled-MAC schedule's ``jnp.roll(x, -delta, axis=-1)``, lowered to the
+    hardware lane rotate (``pltpu.roll``, which takes only non-negative
+    shifts and has ``jnp.roll``'s direction).  ``delta == 0`` returns ``x``
+    untouched: Mosaic refuses the zero-length slice a zero roll lowers to.
+    Same data movement as ``jnp.roll`` on every backend, so arithmetic is
+    unchanged."""
+    if delta == 0:
+        return x
+    return pltpu.roll(x, (-delta) % x.shape[-1], x.ndim - 1)
+
+
+def for_lane_chunks(width: int, g: int, body) -> None:
+    """Run ``body(lanes)`` over the ``g``-lane chunks of a ``width``-lane
+    block row, in a loop, so a batch kernel's code and its temporaries
+    stay one chunk wide however long the requests are.  ``g`` is
+    ``lane_group(d)``: a multiple of 128 (chunks are whole vregs) and of
+    ``d`` (a chunk edge is a point edge, so a roll that wraps inside one
+    chunk only moves lanes of another point onto lanes whose coefficient
+    is zero)."""
+    def step(r, carry):
+        body(pl.ds(pl.multiple_of(r * g, g), g))
+        return carry
+    jax.lax.fori_loop(0, width // g, step, 0)
 
 
 def round_up(x: int, m: int) -> int:
@@ -108,7 +133,9 @@ def stage_packed(pts3: jnp.ndarray, d: int, *, block_rows: int | None = None):
     wr = round_up(max(l * d, g), g)
     if block_rows is None:
         block_rows = packed_budget_rows(wr, pts3.dtype.itemsize)
-    bm = pick_block(b, block_rows, SUBLANES)
+    # a 16-bit block's native tile is (16, 128): two words per sublane
+    align = SUBLANES * max(1, 4 // pts3.dtype.itemsize)
+    bm = pick_block(b, max(align, round_up(block_rows, align)), align)
     bp = round_up(b, bm)
     flat = pts3.reshape(b, l * d)
     xp = jnp.pad(flat, ((0, bp - b), (0, wr - l * d)))

@@ -20,7 +20,6 @@ from repro.configs.shapes import (
     MICROBATCH_PER_SHARD, SHAPES, ShapeSpec, applicability,
 )
 from repro.distributed import sharding
-from repro.launch.mesh import mesh_context
 from repro.distributed.steps import (
     make_decode_step, make_prefill_step, make_train_step,
 )
@@ -116,7 +115,7 @@ def build_cell(arch: str, shape: str, mesh, *,
     psh = sharding.to_shardings(pspecs, mesh, params_shape)
     meta: dict = {"arch": arch, "shape": shape, "kind": spec.kind}
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             accum = specs_in["accum"]
             ospecs = sharding.opt_specs(specs_in["opt_state"], pspecs)

@@ -51,12 +51,13 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool,
     # loop-aware analysis of the partitioned module (cost_analysis counts
     # while bodies once; see repro.hlo_analysis)
     ana = hlo_analysis.analyze(text)
+    chip = roofline.peaks("TPU v5 lite")          # the dry run's target
     roof = roofline.roofline_terms(
         {"flops": ana["flops"], "bytes accessed": ana["hbm_bytes"]},
         roofline.CollectiveStats(ana["collective_bytes"],
-                                 ana["collective_counts"]))
+                                 ana["collective_counts"]), chip)
     mf = cells.model_flops_for_cell(cell, n_devices)
-    util = roofline.model_flops_utilization(mf, roof)
+    util = roofline.model_flops_utilization(mf, roof, chip)
 
     rec.update(
         status="OK",

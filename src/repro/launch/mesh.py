@@ -6,21 +6,11 @@ import jax
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """Version-portable ``jax.make_mesh``: newer jax wants explicit
-    ``axis_types`` (Auto) for the sharding pass; older jax (< AxisType)
-    takes neither the kwarg nor the enum."""
-    kwargs = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def mesh_context(mesh):
-    """Version-portable ``jax.sharding.set_mesh``: on older jax the Mesh
-    object itself is the context manager that scopes named-axis resolution."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding pass
+    propagates layouts, as the model and serving code expect (the
+    library default is ``Explicit``)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, auto, devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

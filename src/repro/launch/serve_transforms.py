@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs, serving
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving import workload
 from repro.serving.workload import timed as _timed
 
@@ -117,6 +118,7 @@ def main(argv=None) -> None:
                          "Chrome-trace JSON (open in Perfetto)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     if args.autotune:
         import repro.autotune
         repro.autotune.set_enabled(True)

@@ -30,8 +30,7 @@ from repro.checkpoint import CheckpointManager, latest_step, load_checkpoint
 from repro.data import DataConfig, SyntheticLMData
 from repro.distributed import elastic, sharding
 from repro.distributed.steps import make_train_step
-from repro.launch.mesh import (make_local_mesh, make_production_mesh,
-                              mesh_context)
+from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import build
 from repro.optim import AdamWConfig, adamw_init
 
@@ -72,7 +71,7 @@ def train_loop(cfg, *, steps: int, global_batch: int, seq_len: int,
     opt_state = jax.tree.map(jax.device_put, opt_state, osh)
 
     step_fn = make_train_step(model, opt_cfg, accum)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step_fn, in_shardings=(psh, osh, None),
                          out_shardings=(psh, osh, None),
                          donate_argnums=(0, 1))

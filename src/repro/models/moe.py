@@ -28,7 +28,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import ambient_mesh, shard_map
+from repro.distributed.sharding import ambient_mesh
 from repro.models.config import ModelConfig
 
 
@@ -144,7 +144,7 @@ def moe_ffn(params, x: jnp.ndarray, cfg: ModelConfig):
             aux = jax.lax.pmean(aux, tp)  # identical, but align replication
         return y, aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, None), ff_spec, ff_spec, ff_spec_down, batch_spec),
         out_specs=(batch_spec, P()), check_vma=False)

@@ -1,4 +1,4 @@
-"""Roofline terms from compiled dry-run artifacts (TPU v5e targets).
+"""Per-chip peaks, and roofline terms from compiled dry-run artifacts.
 
     compute    = device_flops / peak_flops
     memory     = device_bytes / hbm_bw
@@ -18,10 +18,36 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-ICI_LINK_BW = 50e9         # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+    flops: float               # bf16 FLOP/s
+    int8_ops: float            # int8 OP/s
+    hbm_bw: float              # HBM bytes/s
+    hbm_bytes: float           # HBM capacity
+    ici_link_bw: float         # chip-to-chip bytes/s per link
+    source: str
+
+
+#: keyed by ``jax.Device.device_kind``
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, int8_ops=393e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e" (per chip)'),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a chip not in ``PEAKS`` raises (a
+    roofline share against another chip's peaks would be wrong)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -101,24 +127,25 @@ class Roofline:
         return max(self.t_compute, self.t_memory, self.t_collective)
 
 
-def roofline_terms(cost: dict, collectives: CollectiveStats) -> Roofline:
+def roofline_terms(cost: dict, collectives: CollectiveStats,
+                   chip: ChipPeaks) -> Roofline:
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = float(collectives.total_bytes)
-    tc = flops / PEAK_FLOPS
-    tm = hbm / HBM_BW
-    tx = coll / ICI_LINK_BW
+    tc = flops / chip.flops
+    tm = hbm / chip.hbm_bw
+    tx = coll / chip.ici_link_bw
     terms = {"compute": tc, "memory": tm, "collective": tx}
     bottleneck = max(terms, key=terms.get)
     return Roofline(flops, hbm, coll, tc, tm, tx, bottleneck)
 
 
 def model_flops_utilization(model_flops_per_device: float,
-                            roof: Roofline) -> dict:
+                            roof: Roofline, chip: ChipPeaks) -> dict:
     """MODEL_FLOPS/HLO_FLOPs and the roofline fraction of the dominant term."""
     useful = (model_flops_per_device / roof.flops) if roof.flops else 0.0
     # fraction of roofline: time the useful compute would take at peak over
     # the dominant-term time (how close the cell is to its own roofline)
-    t_useful = model_flops_per_device / PEAK_FLOPS
+    t_useful = model_flops_per_device / chip.flops
     frac = t_useful / roof.t_bound if roof.t_bound else 0.0
     return {"useful_flops_ratio": useful, "roofline_fraction": frac}

@@ -26,8 +26,8 @@ missing server loop, built from the paper's M1 execution discipline:
      mask as ``Projected.mask``), the batched ``apply_many`` form of
      PR 1's one-HBM-pass chain kernels.
      Buckets whose packed batch exceeds the launch cap split into shards
-     along the batch axis (and the packed buffer is placed through the
-     ``distributed.sharding`` helpers when a device mesh is ambient).
+     along the batch axis (and under ``jax.set_mesh`` each bucket's rows
+     and folds are sharded over the mesh, one kernel per device).
   4. **Overlap** -- bucket k+1's host->device staging is dispatched while
      bucket k computes, the frame-buffer set-0/set-1 overlap of the paper:
      set 0 is the bucket the RC array (device) is computing on, set 1 is
@@ -37,10 +37,12 @@ Equality contract vs. per-request ``apply`` (asserted by
 ``tests/test_serving.py``): the fold is bit-identical by construction (one
 shared host code path); the fused application runs the same per-request
 arithmetic, but XLA:CPU reserves per-program freedom in contracting float
-multiply-adds, so across *different batch shapes* the last ULP may differ
--- packed results are exact on diagonal plans in practice and within 1 ULP
-on matrix plans, deterministic for a fixed bucket shape, and padded rows
-never contaminate payload rows (points are row-independent).
+multiply-adds, so across *different batch shapes* a float result may
+differ by the rounding of one product -- on diagonal plans at most two
+float32 epsilons of ``|p*s| + |t|``, on matrix and projective plans
+float32-epsilon scale.  Results are bitwise deterministic for a fixed
+bucket shape, and padded rows never contaminate payload rows (points are
+row-independent).
 
 Fixed-point serving: ``submit(..., qformat="q8.7")`` routes a request
 through the int16 Qm.n lane -- it buckets under the FORMAT (the dtype
@@ -59,7 +61,7 @@ detonating later inside a packed bucket.  ``flush`` contains failures
 per LAUNCH: a bucket whose kernel launch fails (or whose output fails
 the corruption check) never takes the other buckets down -- it walks a
 recovery ladder of (1) bounded-exponential-backoff retries, (2) backend
-degradation (``dispatch.fallback_ladder``: pallas -> interpret -> ref),
+degradation (``dispatch.fallback_ladder``: pallas -> ref, interpret -> ref),
 and (3) bisection -- split the bucket in half and recover each half
 independently -- which quarantines a poison request in O(log B)
 launches instead of losing B-1 good ones.  A request whose singleton
@@ -78,6 +80,7 @@ import typing
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import errors, quantize
 from repro.autotune import cache as tuning
@@ -178,6 +181,24 @@ def _count_trace(kernel: str, backend: str, dtype: str, n: int) -> None:
                     backend=backend, dtype=dtype, n=n)
 
 
+def _per_device(body):
+    """Run a bucket body once per device when a mesh is set.  A Mosaic
+    kernel cannot be partitioned by the compiler, so the batch axis is
+    split by ``shard_map`` over the mesh's fsdp axes (``_stage`` placed
+    the rows and their folds that way) and each device traces the body
+    at its own share of the rows.  Rows are independent and staging
+    never changes arithmetic, so each row's result is the one a single
+    device computes."""
+    def call(folded, pts3):
+        mesh = sharding.ambient_mesh()
+        if mesh is None or mesh.size == 1:
+            return body(folded, pts3)
+        spec = P(sharding.axis_names(mesh)[0])
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                             out_specs=spec, check_vma=False)(folded, pts3)
+    return call
+
+
 class Projected(np.ndarray):
     """A projective request's serving result: the projected points as a
     plain ndarray (shape-compatible with ``TransformChain.apply``
@@ -262,8 +283,8 @@ def _compile_batch_q(structure: tuple, backend: str,
             return chain_apply_batch_q(pts3, a, t, n_frac=fmt.n,
                                        backend=backend, config=cfg)
 
-    return BatchPlan(kind=kind, dim=dim, backend=backend, fn=jax.jit(body),
-                     qformat=fmt.name)
+    return BatchPlan(kind=kind, dim=dim, backend=backend,
+                     fn=jax.jit(_per_device(body)), qformat=fmt.name)
 
 
 def _compile_batch(structure: tuple, backend: str) -> BatchPlan:
@@ -306,7 +327,8 @@ def _compile_batch(structure: tuple, backend: str) -> BatchPlan:
             return chain_project_batch(pts3, h, lo, hi, backend=backend,
                                        config=cfg)
 
-    return BatchPlan(kind=kind, dim=dim, backend=backend, fn=jax.jit(body))
+    return BatchPlan(kind=kind, dim=dim, backend=backend,
+                     fn=jax.jit(_per_device(body)))
 
 
 def get_batch_plan(structure: tuple, backend: str,
@@ -761,19 +783,26 @@ class GeometryServer:
     @staticmethod
     def _stage(stacked, packed):
         """Host->device staging for one launch (the set-1 DMA).  When a
-        device mesh is ambient the packed batch is placed sharded over the
-        mesh's fsdp axes via the distributed.sharding helpers, so one
-        launch spans the mesh (SPMD).  On a single device the arrays pass
-        straight to the jitted plan, whose C++ argument path does the
-        transfer -- an explicit ``device_put`` there is measurably pure
-        python dispatch overhead (it dominated the flush profile)."""
-        mesh = sharding.ambient_mesh()
-        if mesh is not None and getattr(mesh, "axis_names", ()) \
-                and math.prod(mesh.shape.values()) > 1:
-            spec = sharding.batch_specs(packed, mesh, accum_dim=False)
-            shard = sharding.to_shardings(spec, mesh, packed)
-            return (jax.device_put(stacked), jax.device_put(packed, shard))
-        return (stacked, packed)
+        device mesh is set (``jax.set_mesh``) the packed batch AND its
+        row-aligned folds are placed sharded over the mesh's fsdp axes,
+        so one launch spans the mesh and each device runs the kernel on
+        its own rows (``_per_device``).  The batch pads with zero rows to
+        a multiple of the fsdp width; unpack reads only the real rows.
+        On a single device the arrays pass straight to the jitted plan,
+        whose C++ argument path does the transfer -- an explicit
+        ``device_put`` there is measurably pure python dispatch overhead
+        (it dominated the flush profile)."""
+        mesh = jax.sharding.get_mesh()
+        if mesh.empty or mesh.size == 1:
+            return (stacked, packed)
+        fsdp, _ = sharding.axis_names(mesh)
+        width = math.prod(mesh.shape[a] for a in fsdp)
+        pad = -len(packed) % width
+
+        def place(x):
+            x = np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+            return jax.device_put(x, NamedSharding(mesh, P(fsdp)))
+        return (tuple(place(x) for x in stacked), place(packed))
 
     # -- fault-injection hooks (no-ops without an injector) ------------------
 

@@ -53,7 +53,7 @@ _SALT = 0xFA17
 #:   flaky   -- launch fails while attempt < flaky_attempts (same rung):
 #:              recovered by RETRY with backoff
 #:   backend -- launch fails on ladder rung 0, any attempt: recovered by
-#:              BACKEND DEGRADATION (pallas -> interpret -> ref)
+#:              BACKEND DEGRADATION (pallas -> ref, interpret -> ref)
 #:   corrupt -- staged words NaN out at (rung 0, attempt 0): detected by
 #:              the output finiteness check, recovered by a pristine
 #:              re-pack RETRY

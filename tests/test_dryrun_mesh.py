@@ -11,7 +11,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run(code: str) -> str:
-    env = dict(os.environ,
+    # the child runs on virtual CPU devices and never tries to take a chip
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.path.join(REPO, "src"))
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

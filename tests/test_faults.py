@@ -228,6 +228,8 @@ class TestRecovery:
 
     def test_backend_fault_degrades_down_the_ladder(self):
         assert dispatch.fallback_ladder("interpret") == ("interpret", "ref")
+        # on a chip a refused kernel never lands in the interpreter
+        assert dispatch.fallback_ladder("pallas") == ("pallas", "ref")
         inj = faults.FaultInjector(backend_tickets=frozenset({0}))
         srv = _fresh(backend="interpret",
                      fault_config=_cfg(max_launch_attempts=2), injector=inj)

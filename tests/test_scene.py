@@ -25,6 +25,7 @@ What is pinned here (see ``docs/scene_graph.md``):
 ``hypothesis`` is an OPTIONAL dependency (see tests/README.md): the
 property tests are skipped without it; the seeded sweeps always run.
 """
+import os
 import subprocess
 import sys
 
@@ -368,8 +369,9 @@ print(np.asarray(f[1]).tobytes().hex())
 
 
 def test_content_keys_and_folds_stable_across_processes():
-    out = subprocess.run(
+    out = subprocess.run(                 # CPU only: never take a chip
         [sys.executable, "-c", _DIGEST_SNIPPET],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, check=True).stdout.split()
     g = scene.SceneGraph(3, cache=scene.FoldCache())
     g.add("w", tc.TransformChain.identity(3).translate(1.0, 2.0, 3.0))
