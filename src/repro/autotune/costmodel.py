@@ -179,10 +179,10 @@ def packed_chain_cost(bsz: int, lpad: int, d: int, kind: str,
 
 @dataclasses.dataclass(frozen=True)
 class LaunchPrediction:
-    """The cost model's view of ONE dispatched serving launch, attached
-    to the launch's trace instant at dispatch time (``serving.engine.
-    _count_launch``) so the profiler can fold predicted-vs-observed
-    ratios out of the span stream.
+    """The cost model's view of ONE dispatched serving launch, computed
+    by the profiler (``obs.profile``) from the shape the launch's trace
+    instant carries, so it can fold predicted-vs-observed ratios out of
+    the span stream without the dispatch path running the model.
 
     ``hbm_bytes`` and ``flops`` come from ``packed_chain_cost``, whose
     byte formula IS ``opcount.packed_chain_bytes`` -- the same number the
@@ -202,8 +202,8 @@ def predict_launch(kind: str, bsz: int, lpad: int, d: int, *,
                    qformat: str | None = None,
                    itemsize: int | None = None) -> LaunchPrediction:
     """Predict one packed-bucket launch (B requests padded to L points)
-    of a serving plan: the per-launch prediction API the engine calls at
-    dispatch time.  ``kind`` is the plan kind (``diag`` / ``matrix`` /
+    of a serving plan: the per-launch prediction API the profiler calls
+    when it folds a launch instant.  ``kind`` is the plan kind (``diag`` / ``matrix`` /
     ``projective``); a non-None ``qformat`` selects the int16 ``_q``
     cost kind (2-byte words), mirroring how the engine's plans carry
     the format separately from the kind."""

@@ -9,11 +9,12 @@ a ``repro.obs.trace`` span stream (PR 8) into
     call counts, total wall time, and SELF wall time (total minus child
     extents), so "where does a flush spend its time" is one table;
   * **per-kernel / per-bucket / per-plan-kind launch tables** -- every
-    ``launch`` instant carries its kernel, bucket track, plan kind,
-    observed HBM bytes, and the cost model's dispatch-time prediction
-    (``autotune.costmodel.predict_launch``: bytes / FLOPs / M1-cycle
-    projection), so launches aggregate along all three axes without
-    re-deriving launch shapes;
+    ``launch`` instant carries its bucket track, plan kind, shape (rows,
+    padded length, dim, word size) and observed HBM bytes; the fold
+    adds the cost model's prediction for that shape
+    (``autotune.costmodel.predict_launch``: kernel, bytes / FLOPs /
+    M1-cycle projection), so the dispatch path never runs the model and
+    launches aggregate along all three axes;
   * **model-error ratios** -- observed/predicted HBM bytes per launch.
     The byte formulas are shared between ``kernels.opcount`` (what the
     engine records) and ``costmodel.packed_chain_cost`` (what it
@@ -91,24 +92,28 @@ class LaunchGroup:
     pred_flops: int = 0
     pred_m1_cycles: int = 0
 
-    def add(self, s: Span) -> None:
+    def add(self, s: Span, pred) -> None:
         a = s.attrs
         self.launches += 1
         self.rows += a.get("rows", 0)
         self.padded_points += a.get("rows", 0) * a.get("lpad", 0)
         self.hbm_bytes += a.get("hbm_bytes", 0)
-        self.pred_hbm_bytes += a.get("pred_hbm_bytes", 0)
-        self.pred_flops += a.get("pred_flops", 0)
-        self.pred_m1_cycles += a.get("pred_m1_cycles", 0)
+        if pred is not None:
+            self.pred_hbm_bytes += pred.hbm_bytes
+            self.pred_flops += pred.flops
+            self.pred_m1_cycles += pred.m1_cycles
 
 
-def _launch_key_kernel(s: Span) -> str:
-    a = s.attrs
-    k = a.get("kernel")
-    if k:
-        return k
-    # pre-prediction streams: reconstruct the kernel name from kind + q
-    return f"{a.get('kind', '?')}{'_q' if a.get('q') else ''}"
+def _predict(a: dict):
+    """The cost model's prediction for a launch instant's shape, or None
+    for a stream whose launches do not carry their dim and word size."""
+    if "dim" not in a or "itemsize" not in a:
+        return None
+    # late import: obs sits below the autotune package in the import graph
+    from repro.autotune import costmodel
+    return costmodel.predict_launch(a["kind"], a["rows"], a["lpad"],
+                                    a["dim"], qformat=a.get("q"),
+                                    itemsize=a["itemsize"])
 
 
 class Profile:
@@ -122,7 +127,7 @@ class Profile:
         self.buckets: dict[str, LaunchGroup] = {}
         self.kinds: dict[str, LaunchGroup] = {}
         #: per-launch observed/predicted HBM byte ratios, stream order
-        #: (empty when the stream predates prediction attachment)
+        #: (empty when the launches do not carry their dim and word size)
         self.byte_ratios: list[float] = []
         self.n_events = len(spans)
         self.n_spans = sum(1 for s in spans if not s.instant)
@@ -152,17 +157,18 @@ class Profile:
 
     def _fold_launch(self, s: Span) -> None:
         a = s.attrs
+        pred = _predict(a)
+        kind = f"{a.get('kind', '?')}{'_q' if a.get('q') else ''}"
         for table, key in (
-                (self.kernels, _launch_key_kernel(s)),
+                (self.kernels, pred.kernel if pred is not None else kind),
                 (self.buckets, s.track or "?"),
-                (self.kinds,
-                 f"{a.get('kind', '?')}{'_q' if a.get('q') else ''}")):
+                (self.kinds, kind)):
             group = table.get(key)
             if group is None:
                 group = table[key] = LaunchGroup(key)
-            group.add(s)
-        if a.get("pred_hbm_bytes"):
-            self.byte_ratios.append(a["hbm_bytes"] / a["pred_hbm_bytes"])
+            group.add(s, pred)
+        if pred is not None and pred.hbm_bytes:
+            self.byte_ratios.append(a["hbm_bytes"] / pred.hbm_bytes)
 
     # -- deterministic reads --------------------------------------------------
 
@@ -241,7 +247,7 @@ class Profile:
                     f"{self.byte_ratio_exact}"]
         else:
             out.append("- no launches carried predictions "
-                       "(pre-prediction span stream)")
+                       "(launches without their dim and word size)")
         return "\n".join(out) + "\n"
 
 

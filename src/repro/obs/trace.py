@@ -39,6 +39,13 @@ Design rules (each one is load-bearing):
     (``obs.recorder.FlightRecorder``); every finished span is offered to
     it, so the last-N-events window is always current when a
     ``LaunchError`` post-mortem wants a snapshot.
+  * **Profiler mirror.**  ``Tracer(annotate=True)`` also opens a
+    ``jax.profiler.TraceAnnotation`` named after each span that
+    ``begin`` opens and closes it in ``end``, so under
+    ``jax.profiler.start_trace`` the program's phases land in the
+    ``.xplane.pb`` on the device trace's clock.  Instants and
+    retroactive ``complete`` spans have no extent to open at their
+    start, so they are not mirrored.
 
 This module deliberately imports nothing from ``repro.serving`` (the
 engine imports *us*; a clock import back into the package would cycle).
@@ -150,11 +157,15 @@ class Tracer:
     ``complete`` records a retroactive span (queue-wait spans are known
     only once the wait is over); ``instant`` records a zero-extent event.
     Keyword arguments become span attributes except the reserved
-    ``ticket`` / ``tickets`` / ``track`` tags."""
+    ``ticket`` / ``tickets`` / ``track`` tags.
+
+    ``annotate=True`` mirrors every ``begin``/``end`` span (not instants,
+    not ``complete``) as a ``jax.profiler.TraceAnnotation`` of the same
+    name; jax is imported only then."""
 
     enabled = True
 
-    def __init__(self, clock=None, recorder=None):
+    def __init__(self, clock=None, recorder=None, annotate=False):
         #: any ``.now() -> float`` duck; serving.clock.Clock instances
         #: qualify, and a VirtualClock makes the stream deterministic
         self.clock = clock
@@ -163,6 +174,13 @@ class Tracer:
         self.recorder = recorder
         self.spans: list[Span] = []
         self._stack: list[int] = []
+        #: open profiler annotations as (sid, annotation), parallel to
+        #: ``_stack``; None when not mirroring
+        self._marks: list | None = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+            self._marks = []
 
     # -- emission ------------------------------------------------------------
 
@@ -181,8 +199,16 @@ class Tracer:
     def begin(self, name: str, *, ticket=None, tickets=(), track=None,
               **attrs) -> int:
         """Open a span; returns its id for the matching ``end``."""
-        s = self._push(name, self._now(), None, False,
-                       ticket, tickets, track, attrs)
+        t0 = self._now()
+        if self._marks is not None:
+            # built and opened right after the clock is read, closed right
+            # after it in ``end``: building takes about as long as
+            # closing, so both clocks see the same extent to within a
+            # microsecond
+            mark = self._annotation(name)
+            mark.__enter__()
+            self._marks.append((len(self.spans), mark))
+        s = self._push(name, t0, None, False, ticket, tickets, track, attrs)
         self._stack.append(s.sid)
         return s.sid
 
@@ -193,6 +219,8 @@ class Tracer:
         it opened (the async submit span)."""
         s = self.spans[sid]
         s.t1 = self._now()
+        if self._marks is not None:
+            self._unmark(sid)
         if attrs:
             s.attrs.update(attrs)
         if ticket is not None:
@@ -205,6 +233,15 @@ class Tracer:
             self._stack.pop()
         if self.recorder is not None:
             self.recorder.record(s)
+
+    def _unmark(self, sid: int) -> None:
+        """Close the profiler annotations down to ``sid``'s, popping
+        through as ``end`` pops the stack, so they close in LIFO order."""
+        while self._marks:
+            top, mark = self._marks.pop()
+            mark.__exit__(None, None, None)
+            if top == sid:
+                break
 
     def instant(self, name: str, *, ticket=None, tickets=(), track=None,
                 **attrs) -> None:

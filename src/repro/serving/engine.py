@@ -469,10 +469,13 @@ def _bucket_track(structure: tuple, backend: str, dt: str,
     return f"{_structure_tag(structure)}|{backend}|{dt}|{lpad}"
 
 
-#: plan kind -> the batch kernel whose tuning-cache entry a launch
-#: consults (the launch span's ``config`` annotation names its source)
-_KERNEL_BY_KIND = {"diag": "chain_diag_batch", "matrix": "chain_apply_batch",
-                   "projective": "chain_project_batch"}
+def _fetch(plan: BatchPlan, out):
+    """A launch's outputs as host arrays (for a projective plan, its
+    points and its mask), waiting for the device where it has not
+    finished."""
+    if plan.kind == "projective":
+        return np.asarray(out[0]), np.asarray(out[1])
+    return np.asarray(out)
 
 
 class GeometryServer:
@@ -850,30 +853,32 @@ class GeometryServer:
         report.launches += 1
         trc = obst.active()
         if trc.enabled:
-            # per-attempt annotation: backend rung, plan kind, autotune
-            # config source, the opcount HBM bytes this launch moves, and
-            # the cost model's per-launch prediction (bytes / FLOPs / M1
-            # cycle projection) -- attached at dispatch time so the
-            # profiler can fold predicted-vs-observed ratios out of the
-            # span stream without re-deriving launch shapes
-            from repro.autotune import costmodel  # late: traced path only
-            dtype = plan.qformat if plan.qformat is not None \
-                else str(packed.dtype)
-            kernel = _KERNEL_BY_KIND[plan.kind] \
-                + ("_q" if plan.qformat else "")
-            cfg = tuning.config_for(kernel, plan.backend, dtype,
-                                    len(reqs) * lpad)
-            pred = costmodel.predict_launch(
-                plan.kind, len(reqs), lpad, plan.dim,
-                qformat=plan.qformat, itemsize=packed.dtype.itemsize)
+            # per-attempt annotation: backend rung, the launch's shape and
+            # the opcount HBM bytes it moves -- what is at hand here; the
+            # profiler derives the cost model's prediction from these
+            # when it folds the stream, off the dispatch path
             trc.instant(
                 "launch", tickets=tuple(r.ticket for r in reqs),
                 track=track, backend=plan.backend, kind=plan.kind,
                 q=plan.qformat, rung=rung, attempt=attempt,
-                rows=len(reqs), lpad=lpad, kernel=pred.kernel,
-                hbm_bytes=nbytes, pred_hbm_bytes=pred.hbm_bytes,
-                pred_flops=pred.flops, pred_m1_cycles=pred.m1_cycles,
-                config=cfg.source)
+                rows=len(reqs), lpad=lpad, dim=plan.dim,
+                itemsize=packed.dtype.itemsize, hbm_bytes=nbytes)
+
+    @staticmethod
+    def _call(plan: BatchPlan, dev: tuple, track: str | None):
+        """The jitted plan call of one counted launch; it transfers the
+        host operands and enqueues the kernel.  Under a tracer it is the
+        ``launch.call`` span, one per ``_count_launch``, marked
+        ``traced=True`` when the call traced a new shape."""
+        trc = obst.active()
+        if not trc.enabled:
+            return plan.fn(*dev)
+        traces = stats["traces"]
+        sid = trc.begin("launch.call", track=track)
+        try:
+            return plan.fn(*dev)
+        finally:
+            trc.end(sid, traced=stats["traces"] != traces)
 
     # -- flush: dispatch, unpack, recover ------------------------------------
 
@@ -987,11 +992,10 @@ class GeometryServer:
             try:
                 if isinstance(staged, _FailedLaunch):
                     raise staged.err
-                dev_params, dev_points = staged
                 self._check_injected(L.reqs, 0, 0)
                 self._count_launch(L.plan, L.lpad, L.reqs, L.packed, L.report,
                                    rung=0, attempt=0, track=L.track)
-                outs.append(L.plan.fn(dev_params, dev_points))  # async: set 0
+                outs.append(self._call(L.plan, staged, L.track))  # set 0
             except Exception as e:
                 outs.append(_FailedLaunch(e))
             if k + 1 < len(launches):
@@ -1016,7 +1020,7 @@ class GeometryServer:
                             error=type(out.err).__name__)
                 continue
             try:
-                self._unpack(L.plan, L.reqs, out, results)
+                self._unpack(L.plan, L.reqs, out, results, L.track)
             except Exception as e:
                 self._bump("launch_failures")
                 failed.append((L, e))
@@ -1043,19 +1047,37 @@ class GeometryServer:
             trc.end(fsid, buckets=len(buckets), launches=len(launches))
         return [results[p.ticket] for p in pending]
 
-    def _unpack(self, plan: BatchPlan, reqs: list, out,
-                results: dict) -> None:
+    def _unpack(self, plan: BatchPlan, reqs: list, out, results: dict,
+                track: str | None) -> None:
         """Unpack one launch: one device->host sync, then numpy slicing --
         per-request unpack must not become per-request dispatch again (a
         jax slice per request would re-pay the launch overhead the
-        batching just removed).  Each result is a payload-sized COPY: a
-        view would be read-only and would pin the whole padded batch
-        buffer for as long as the caller keeps any one result.
-        Projective launches return (points, mask); their results carry
-        the per-point cull mask as ``Projected.mask``."""
+        batching just removed).  Under a tracer its three parts are
+        spans on the launch's track: ``unpack.wait`` (the host blocked
+        on the device), ``unpack.fetch`` (the device->host transfer of
+        the ready outputs) and ``unpack.copy`` (``_resolve``)."""
+        trc = obst.active()
+        if not trc.enabled:
+            self._resolve(plan, reqs, _fetch(plan, out), results)
+            return
+        with trc.span("unpack.wait", track=track):
+            jax.block_until_ready(out)
+        with trc.span("unpack.fetch", track=track):
+            host = _fetch(plan, out)
+        with trc.span("unpack.copy", track=track):
+            self._resolve(plan, reqs, host, results)
+
+    def _resolve(self, plan: BatchPlan, reqs: list, host,
+                 results: dict) -> None:
+        """Each request's result from one launch's host output.  Each is
+        a payload-sized COPY: a view would be read-only and would pin
+        the whole padded batch buffer for as long as the caller keeps
+        any one result.  Projective launches return (points, mask);
+        their results carry the per-point cull mask as
+        ``Projected.mask``."""
         trc = obst.active()
         if plan.kind == "projective":
-            host, mask = np.asarray(out[0]), np.asarray(out[1])
+            host, mask = host
             for i, r in enumerate(reqs):
                 results[r.ticket] = _projected(
                     np.array(host[i, :r.n].reshape(r.points.shape)),
@@ -1065,7 +1087,6 @@ class GeometryServer:
                     trc.instant("request.resolve", ticket=r.ticket,
                                 outcome="ok")
             return
-        host = np.asarray(out)
         if self.fault_config.validate_outputs and plan.qformat is None \
                 and not np.isfinite(host).all():
             # inputs validated finite at submit, so a non-finite output
@@ -1137,8 +1158,8 @@ class GeometryServer:
                     self._check_injected(reqs, ri, attempt)
                     self._count_launch(plan, L.lpad, reqs, packed, L.report,
                                        rung=ri, attempt=attempt, track=rtrack)
-                    out = plan.fn(*dev)
-                    self._unpack(plan, reqs, out, results)
+                    out = self._call(plan, dev, rtrack)
+                    self._unpack(plan, reqs, out, results, rtrack)
                 except Exception as e:
                     self._bump("launch_failures")
                     err = e

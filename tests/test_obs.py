@@ -14,7 +14,7 @@ import pytest
 
 from repro import obs, serving
 from repro.core import transform_chain as tc
-from repro.serving import engine, faults
+from repro.serving import engine, faults, workload
 from repro.serving.async_engine import AsyncGeometryServer, SLOConfig
 from repro.serving.clock import VirtualClock
 
@@ -110,6 +110,56 @@ class TestTracer:
                 assert obs.active() is inner
             assert obs.active() is trc
         assert not obs.active().enabled
+
+    @staticmethod
+    def _record_annotations(monkeypatch):
+        """Stand in for ``jax.profiler.TraceAnnotation``: each instance
+        logs its enter and exit."""
+        import jax.profiler
+        log = []
+
+        class Mark:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+                return False
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Mark)
+        return log
+
+    def test_annotate_mirrors_each_extent_span_in_lifo_order(
+            self, monkeypatch):
+        log = self._record_annotations(monkeypatch)
+        trc = obs.Tracer(clock=VirtualClock(), annotate=True)
+        a = trc.begin("outer")
+        trc.instant("mark")                  # instants are not mirrored
+        trc.complete("retro", 0.0, 1.0)      # nor retroactive spans
+        with pytest.raises(RuntimeError):
+            with trc.span("work"):
+                trc.begin("inner")           # left open by the raise
+                raise RuntimeError("boom")
+        b = trc.begin("late")
+        trc.begin("orphan")
+        trc.end(b)                           # out of order: pops through
+        trc.end(a)
+        assert log == [("enter", "outer"), ("enter", "work"),
+                       ("enter", "inner"), ("exit", "inner"),
+                       ("exit", "work"), ("enter", "late"),
+                       ("enter", "orphan"), ("exit", "orphan"),
+                       ("exit", "late"), ("exit", "outer")]
+        assert trc.n_spans == 6 and trc.n_events == 7
+
+    def test_annotate_off_never_touches_jax(self, monkeypatch):
+        log = self._record_annotations(monkeypatch)
+        trc = obs.Tracer(clock=VirtualClock())
+        with trc.span("work"):
+            trc.end(trc.begin("inner"))
+        assert log == [] and trc.n_spans == 2
 
     def test_null_tracer_is_inert(self):
         n = obs.NullTracer()
@@ -379,6 +429,74 @@ class TestEngineTracing:
         assert untraced == traced
         assert trc.n_events > 0
 
+    def test_unpack_splits_into_wait_fetch_copy(self):
+        srv = _fresh(backend="ref")
+        trc = obs.Tracer(clock=VirtualClock())
+        with obs.installed(trc):
+            for chain, pts, qname in workload.mixed_lane_workload(
+                    3, 24, max_points=40):
+                srv.submit(chain, pts, qformat=qname)
+            srv.flush()
+        unpacks = [s for s in trc.spans if s.name == "unpack"]
+        assert len(unpacks) == serving.stats["launches"] > 1
+        assert {s.attrs["kind"] for s in trc.spans
+                if s.name == "launch"} == {"diag", "matrix", "projective"}
+        for u in unpacks:
+            kids = [s for s in trc.spans if s.parent == u.sid]
+            assert [k.name for k in kids] == ["unpack.wait", "unpack.fetch",
+                                              "unpack.copy"]
+            # bucket-track spans that no request's tree collects
+            assert all(k.track == u.track and k.ticket is None
+                       and not k.tickets for k in kids)
+        # the per-request resolutions happen in the copy
+        copies = {s.sid for s in trc.spans if s.name == "unpack.copy"}
+        assert all(trc.spans[s.parent].sid in copies for s in trc.spans
+                   if s.name == "request.resolve")
+        for t in trc.tickets_seen():
+            names = {s.name for root in trc.span_tree(t)
+                     for s in root.walk()}
+            assert not names & {"unpack.wait", "unpack.fetch",
+                                "unpack.copy", "launch.call"}
+
+    def test_launch_call_marks_the_calls_that_traced(self):
+        srv = _fresh(backend="ref")
+        engine.clear_plan_cache()
+        trc = obs.Tracer(clock=VirtualClock())
+        with obs.installed(trc):
+            for sizes in ((4, 9, 30), (4, 9, 30), (4, 70)):
+                for n in sizes:
+                    srv.submit(_chain2(), _pts(n))
+                srv.flush()
+        calls = [s for s in trc.spans if s.name == "launch.call"]
+        assert len(calls) == trc.count("launch") == \
+            serving.stats["launches"] > 0
+        # a call traced exactly when a plan.trace instant fell inside it
+        for c in calls:
+            traced = any(s.parent == c.sid for s in trc.spans
+                         if s.name == "plan.trace")
+            assert c.attrs["traced"] is traced
+            assert c.track and c.ticket is None and not c.tickets
+            assert trc.spans[c.parent].name == "flush.dispatch"
+        assert sum(c.attrs["traced"] for c in calls) == \
+            serving.stats["traces"] > 0
+        assert not all(c.attrs["traced"] for c in calls)
+
+    def test_launch_instant_carries_no_prediction(self, monkeypatch):
+        from repro.autotune import costmodel
+
+        def refuse(*a, **k):
+            raise AssertionError("cost model called on the serving path")
+        monkeypatch.setattr(costmodel, "predict_launch", refuse)
+        srv = _fresh(backend="ref")
+        trc = obs.Tracer(clock=VirtualClock())
+        with obs.installed(trc):
+            srv.submit(_chain2(), _pts(8))
+            srv.flush()
+        (launch,) = [s for s in trc.spans if s.name == "launch"]
+        assert set(launch.attrs) == {"backend", "kind", "q", "rung",
+                                     "attempt", "rows", "lpad", "dim",
+                                     "itemsize", "hbm_bytes"}
+
     def test_bucket_tracks_and_labeled_dimensions(self):
         srv = _fresh(backend="ref")
         trc = obs.Tracer(clock=VirtualClock())
@@ -441,6 +559,20 @@ class TestSpanTreesUnderFaults:
                     if s.name == "request.resolve"]
             assert outs == ["ok"]
         assert trc.count("launch") == serving.stats["launches"]
+
+    def test_launch_call_per_launch_through_recovery(self):
+        inj = faults.FaultInjector(flaky_tickets=frozenset({1}),
+                                   flaky_attempts=1,
+                                   poison_tickets=frozenset({4}))
+        srv, trc, tickets, results = self._traced_faulty(inj)
+        assert serving.stats["retries"] > 0 and \
+            serving.stats["bisections"] > 0
+        assert trc.count("launch.call") == trc.count("launch") == \
+            serving.stats["launches"]
+        recovered = [s for s in trc.spans if s.name == "launch.call"
+                     and trc.spans[s.parent].name == "recover.attempt"]
+        assert recovered and all(str(s.track).startswith("recovery:")
+                                 for s in recovered)
 
     def test_every_ticket_accounted_under_mixed_faults(self):
         inj = faults.FaultInjector(flaky_tickets=frozenset({0}),

@@ -13,6 +13,7 @@ The load-bearing invariants:
   * ``tools/bench_trend.py`` exits 0 on the real committed trajectory
     and 1 on a synthetic worsened-counter fixture.
 """
+import dataclasses
 import json
 import os
 
@@ -119,6 +120,37 @@ class TestProfileAttribution:
         assert c["byte_ratio_exact"] == 1
         assert c["hbm_bytes"] == c["pred_hbm_bytes"] > 0
         assert c["pred_flops"] > 0 and c["pred_m1_cycles"] > 0
+
+    def test_counters_pinned(self, smoke):
+        # the launch tables and the model error are what they were when
+        # the prediction rode on the launch instant; the stream itself
+        # gained launch.call and the three unpack.* spans per launch
+        _tracer, _server, prof = smoke
+        assert prof.counters() == {
+            "events": 531, "spans": 347, "launches": 40, "kernels": 5,
+            "launch_buckets": 35, "hbm_bytes": 21872,
+            "pred_hbm_bytes": 21872, "pred_flops": 22224,
+            "pred_m1_cycles": 4715, "byte_ratio_exact": 1}
+
+    def test_fold_predicts_from_the_launch_shape(self, smoke):
+        tracer, _server, prof = smoke
+        launches = [s for s in tracer.spans if s.name == "launch"]
+        preds = [costmodel.predict_launch(
+            s.attrs["kind"], s.attrs["rows"], s.attrs["lpad"],
+            s.attrs["dim"], qformat=s.attrs["q"],
+            itemsize=s.attrs["itemsize"]) for s in launches]
+        assert set(prof.kernels) == {p.kernel for p in preds}
+        assert sum(g.pred_flops for g in prof.kernels.values()) == \
+            sum(p.flops for p in preds)
+        # a stream whose launches lack their shape folds no prediction
+        bare = [dataclasses.replace(s, attrs={
+            k: v for k, v in s.attrs.items() if k not in ("dim",
+                                                          "itemsize")})
+            for s in tracer.spans]
+        old = Profile.from_spans(bare)
+        assert old.launches == prof.launches and old.byte_ratios == []
+        assert old.counters()["pred_hbm_bytes"] == 0
+        assert set(old.kernels) == set(old.kinds)
 
     def test_deterministic_across_runs(self, smoke):
         _tracer, _server, prof = smoke
