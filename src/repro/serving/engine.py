@@ -106,6 +106,10 @@ from repro.serving import errors as serrors
 #:   buckets       -- plan buckets executed
 #:   shards        -- extra launches from splitting oversized buckets
 #:   payload_points / padded_points -- real vs padded points moved
+#:   prefetches    -- launches whose device->host copy ``flush`` started
+#:                    right after the plan call; equals ``launches`` on
+#:                    a flush that needed no recovery (recovery's
+#:                    launches unpack at once and are not prefetched)
 #: fault-tolerance counters (all deterministic under a seeded injector;
 #: the chaos CI lane gates on them exactly):
 #:   rejected_requests  -- submissions refused with a typed RequestError
@@ -124,7 +128,7 @@ from repro.serving import errors as serrors
 #:   rate_limit_rejections  -- typed RateLimitError token-bucket refusals
 _STAT_KEYS = ("plan_compiles", "plan_hits", "traces", "launches",
               "requests", "buckets", "shards",
-              "payload_points", "padded_points",
+              "payload_points", "padded_points", "prefetches",
               "rejected_requests", "q_fallbacks", "launch_failures",
               "retries", "backend_fallbacks", "bisections",
               "recovered_requests", "failed_requests",
@@ -469,10 +473,19 @@ def _bucket_track(structure: tuple, backend: str, dt: str,
     return f"{_structure_tag(structure)}|{backend}|{dt}|{lpad}"
 
 
+def _prefetch(out) -> None:
+    """Start the device->host copy of every output of one launch (a
+    projective launch has two: points and mask; a sharded output one
+    copy per shard) without waiting for it; ``_fetch`` later reuses
+    the copy instead of starting its own."""
+    for leaf in jax.tree.leaves(out):
+        leaf.copy_to_host_async()
+
+
 def _fetch(plan: BatchPlan, out):
     """A launch's outputs as host arrays (for a projective plan, its
     points and its mask), waiting for the device where it has not
-    finished."""
+    finished, and for the copy ``_prefetch`` started."""
     if plan.kind == "projective":
         return np.asarray(out[0]), np.asarray(out[1])
     return np.asarray(out)
@@ -975,8 +988,11 @@ class GeometryServer:
         # computing (set 0) while the next launch's host->device transfer
         # streams (set 1).  Nothing blocks until unpack -- jax's async
         # dispatch provides the overlap; this loop just orders the work so
-        # it CAN overlap.  A launch that raises is recorded and skipped,
-        # never aborting its siblings.
+        # it CAN overlap.  Each dispatched launch's copy back to the host
+        # starts as soon as its call returns, so it runs under the later
+        # launches' calls instead of one after another in unpack.  A
+        # launch that raises is recorded and skipped, never aborting its
+        # siblings.
         def _stage_first(L: _Launch):
             try:
                 return self._stage_attempt(L.plan, L.stacked, L.packed,
@@ -987,6 +1003,7 @@ class GeometryServer:
         dsid = trc.begin("flush.dispatch", launches=len(launches)) \
             if trc.enabled else None
         outs: list = []
+        prefetched = 0
         staged = _stage_first(launches[0]) if launches else None
         for k, L in enumerate(launches):
             try:
@@ -995,13 +1012,17 @@ class GeometryServer:
                 self._check_injected(L.reqs, 0, 0)
                 self._count_launch(L.plan, L.lpad, L.reqs, L.packed, L.report,
                                    rung=0, attempt=0, track=L.track)
-                outs.append(self._call(L.plan, staged, L.track))  # set 0
+                out = self._call(L.plan, staged, L.track)       # set 0
+                _prefetch(out)
+                outs.append(out)
+                prefetched += 1
             except Exception as e:
                 outs.append(_FailedLaunch(e))
             if k + 1 < len(launches):
                 staged = _stage_first(launches[k + 1])          # async: set 1
+        self._bump("prefetches", prefetched)
         if dsid is not None:
-            trc.end(dsid)
+            trc.end(dsid, prefetched=prefetched)
 
         # Phase 2 -- unpack with capture: materialisation is where async
         # device errors (and injected corruption) actually surface, so
@@ -1055,7 +1076,8 @@ class GeometryServer:
         batching just removed).  Under a tracer its three parts are
         spans on the launch's track: ``unpack.wait`` (the host blocked
         on the device), ``unpack.fetch`` (the device->host transfer of
-        the ready outputs) and ``unpack.copy`` (``_resolve``)."""
+        the ready outputs, or in ``flush`` the rest of the copy that
+        phase 1 started) and ``unpack.copy`` (``_resolve``)."""
         trc = obst.active()
         if not trc.enabled:
             self._resolve(plan, reqs, _fetch(plan, out), results)

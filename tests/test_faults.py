@@ -350,6 +350,104 @@ class TestRecovery:
 
 
 # ---------------------------------------------------------------------------
+# the copy back started at dispatch, under failure
+# ---------------------------------------------------------------------------
+
+class TestPrefetchUnderFaults:
+    def test_launch_failing_at_dispatch_is_not_prefetched(self, host_copies):
+        """Ticket 0's first attempt is blocked before it reaches the
+        device: phase 1 prefetches only the other bucket, and recovery
+        unpacks its relaunch at once, without a prefetch."""
+        inj = faults.FaultInjector(flaky_tickets=frozenset({0}),
+                                   flaky_attempts=1)
+        srv = _fresh(backend="ref", fault_config=_cfg(), injector=inj)
+        chain3 = tc.TransformChain.identity(3).translate(1.0, 2.0, 3.0)
+        pts2, pts3 = _pts(), _pts(8, 3)
+        srv.submit(_chain2(), pts2)
+        srv.submit(chain3, pts3)
+        out = srv.flush()
+        np.testing.assert_allclose(out[0], np.asarray(_chain2().apply(pts2)),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(out[1], np.asarray(chain3.apply(pts3)),
+                                   rtol=1e-6, atol=1e-6)
+        assert serving.stats["launches"] == 2       # bucket 1, recovery
+        assert serving.stats["prefetches"] == 1
+        assert host_copies == [(1, 8, 3)]           # bucket 1's (B, L, d)
+        assert srv.metrics.value("prefetches") == 1
+
+    def test_corruption_still_caught_after_prefetch(self, host_copies):
+        """The corrupted launch dispatches and is prefetched; the finite
+        check at unpack still sees the corrupted copy and recovery
+        re-packs from the pristine host copy."""
+        inj = faults.FaultInjector(corrupt_tickets=frozenset({0}))
+        srv = _fresh(backend="ref", fault_config=_cfg(), injector=inj)
+        chain, pts = _chain2(), _pts()
+        srv.submit(chain, pts)
+        (out,) = srv.flush()
+        np.testing.assert_allclose(out, np.asarray(chain.apply(pts)),
+                                   rtol=1e-6, atol=1e-6)
+        assert inj.injected_corruptions == 1
+        assert serving.stats["launch_failures"] == 1
+        assert serving.stats["recovered_requests"] == 1
+        assert serving.stats["launches"] == 2
+        assert serving.stats["prefetches"] == len(host_copies) == 1
+
+    def test_failing_neighbour_keeps_prefetched_results(self):
+        """A poisoned bucket among three clean ones (diag, matrix,
+        projective): every clean bucket's prefetched result comes back
+        intact and only the dispatched launches count as prefetched."""
+        inj = faults.FaultInjector(poison_tickets=frozenset({0}))
+        srv = _fresh(backend="ref",
+                     fault_config=_cfg(max_launch_attempts=2), injector=inj)
+        rng = np.random.default_rng(61)
+        clean = [(workload.chain_for(rng, d, kinds), _pts(12, d))
+                 for d, kinds in ((2, "TST"), (3, "TRS"), (3, "MPC"))]
+        srv.submit(_chain2(), _pts())                 # ticket 0: poison
+        for chain, pts in clean:
+            srv.submit(chain, pts)
+        out = srv.flush()
+        assert isinstance(out[0], errors.LaunchError)
+        for (chain, pts), got in zip(clean, out[1:]):
+            if chain.is_projective:
+                exp, mask = chain.project(pts)
+                np.testing.assert_array_equal(got.mask, np.asarray(mask))
+            else:
+                exp = chain.apply(pts)
+            np.testing.assert_allclose(got, np.asarray(exp),
+                                       rtol=1e-5, atol=1e-5)
+        assert serving.stats["prefetches"] == 3
+        assert sum(r.launches for r in srv.last_report
+                   if r.failed_requests == 0) == 3
+
+    def test_prefetch_that_raises_is_a_failed_launch(self, monkeypatch):
+        """A copy that cannot start fails its launch like a failed
+        dispatch: recovery serves it and the siblings are untouched."""
+        real = engine._prefetch
+        seen = []
+
+        def flaky_prefetch(out):
+            seen.append(out)
+            if len(seen) == 1:
+                raise RuntimeError("copy refused")
+            real(out)
+        monkeypatch.setattr(engine, "_prefetch", flaky_prefetch)
+        srv = _fresh(backend="ref", fault_config=_cfg())
+        chain3 = tc.TransformChain.identity(3).translate(1.0, 2.0, 3.0)
+        pts2, pts3 = _pts(), _pts(8, 3)
+        srv.submit(_chain2(), pts2)
+        srv.submit(chain3, pts3)
+        out = srv.flush()
+        np.testing.assert_allclose(out[0], np.asarray(_chain2().apply(pts2)),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(out[1], np.asarray(chain3.apply(pts3)),
+                                   rtol=1e-6, atol=1e-6)
+        assert serving.stats["launch_failures"] == 1
+        assert serving.stats["recovered_requests"] == 1
+        assert serving.stats["launches"] == 3
+        assert serving.stats["prefetches"] == 1
+
+
+# ---------------------------------------------------------------------------
 # the chaos soak harness
 # ---------------------------------------------------------------------------
 

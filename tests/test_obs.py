@@ -481,6 +481,35 @@ class TestEngineTracing:
             serving.stats["traces"] > 0
         assert not all(c.attrs["traced"] for c in calls)
 
+    def test_dispatch_end_carries_prefetched(self):
+        """``flush.dispatch`` ends with ``prefetched``: the launches of
+        phase 1 whose copy back started, one per ``launch`` instant
+        there; recovery's launches add instants and calls, not
+        prefetches."""
+        inj = faults.FaultInjector(flaky_tickets=frozenset({0}),
+                                   flaky_attempts=2)
+        srv = _fresh(backend="ref", fault_config=_cfg(), injector=inj)
+        trc = obs.Tracer(clock=VirtualClock())
+        with obs.installed(trc):
+            for chain, pts, qname in workload.mixed_lane_workload(
+                    5, 24, max_points=40):
+                srv.submit(chain, pts, qformat=qname)
+            srv.flush()
+        (dispatch,) = [s for s in trc.spans if s.name == "flush.dispatch"]
+        phase1 = [s for s in trc.spans
+                  if s.name == "launch" and s.parent == dispatch.sid]
+        assert dispatch.attrs["prefetched"] == len(phase1) == \
+            serving.stats["prefetches"] > 1
+        # ticket 0's bucket was blocked at dispatch and relaunched
+        assert serving.stats["launches"] > serving.stats["prefetches"]
+        calls = [s for s in trc.spans if s.name == "launch.call"]
+        assert len(calls) == serving.stats["launches"]
+        assert sum(c.parent == dispatch.sid for c in calls) == len(phase1)
+        for u in [s for s in trc.spans if s.name == "unpack"]:
+            kids = [s.name for s in trc.spans if s.parent == u.sid]
+            assert kids == (["unpack.wait", "unpack.fetch", "unpack.copy"]
+                            if u.attrs["outcome"] == "ok" else [])
+
     def test_launch_instant_carries_no_prediction(self, monkeypatch):
         from repro.autotune import costmodel
 

@@ -1,8 +1,15 @@
 """Batched transform-serving engine tests: packed-batch equality against
 per-request ``apply``, the size-bucketing waste cap, the one-compile-per-
 structure (no-retrace) guarantee under load, oversized-bucket sharding,
-and the packed-batch launch/byte accounting.
+the packed-batch launch/byte accounting, and the device-to-host copy
+each dispatched launch starts at dispatch.
 """
+import json
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -10,7 +17,7 @@ import pytest
 from repro import serving
 from repro.core import transform_chain as tc
 from repro.kernels import opcount
-from repro.serving import bucketing, workload
+from repro.serving import bucketing, engine, workload
 
 
 def _fresh_server(**kw):
@@ -335,3 +342,119 @@ def test_stats_launch_invariant_holds_through_recovery():
         assert serving.stats["launches"] == \
             sum(r.launches for r in srv.reports)
     assert serving.stats["launch_failures"] > 0     # the ladder really ran
+
+
+# ---------------------------------------------------------------------------
+# the device->host copy starts at dispatch
+# ---------------------------------------------------------------------------
+
+def _mixed_requests(seed):
+    return workload.random_workload(np.random.default_rng(seed), 24,
+                                    max_points=100)
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_flush_prefetches_every_output_leaf(backend, host_copies):
+    srv = _serve_and_compare(backend, _mixed_requests(11))
+    kinds = {r.kind for r in srv.last_report}
+    assert kinds == {"diag", "matrix", "projective"}
+    # one copy per output leaf: a projective launch has points and mask
+    assert len(host_copies) == sum(
+        r.launches * (2 if r.kind == "projective" else 1)
+        for r in srv.last_report)
+    assert serving.stats["prefetches"] == serving.stats["launches"] > 1
+    assert srv.metrics.value("prefetches") == serving.stats["launches"]
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_prefetched_results_are_bitwise_unprefetched(backend, monkeypatch):
+    """Only the moment of the copy moves: the same flush served without
+    the prefetch gives the same bits, cull masks included."""
+    reqs = _mixed_requests(12)
+    with_copy = _fresh_server(backend=backend).serve(reqs)
+    assert serving.stats["prefetches"] == serving.stats["launches"] > 1
+    assert any(isinstance(r, serving.Projected) for r in with_copy)
+    monkeypatch.setattr(engine, "_prefetch", lambda out: None)
+    without = serving.GeometryServer(backend=backend).serve(reqs)
+    for a, b in zip(with_copy, without):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        if isinstance(a, serving.Projected):
+            assert np.array_equal(a.mask, b.mask)
+
+
+def test_one_launch_flush_prefetches_once(host_copies):
+    srv = _serve_and_compare(
+        "ref", [(workload.chain_for(np.random.default_rng(3), 3, "MPC"),
+                 np.random.default_rng(4).standard_normal((40, 3))
+                 .astype(np.float32))])
+    assert serving.stats["launches"] == serving.stats["prefetches"] == 1
+    assert len(host_copies) == 2               # projected points, mask
+    assert srv.metrics.value("prefetches") == 1
+
+
+@pytest.mark.parametrize("pending", ["none", "identity"])
+def test_flush_without_launches_prefetches_nothing(pending, host_copies):
+    srv = _fresh_server(backend="ref")
+    if pending == "identity":
+        srv.submit(tc.TransformChain.identity(2),
+                   np.ones((4, 2), np.float32))
+    assert len(srv.flush()) == (pending == "identity")
+    assert serving.stats["launches"] == serving.stats["prefetches"] == 0
+    assert host_copies == [] and srv.metrics.value("prefetches") == 0
+
+
+_MESH_PREFETCH = """
+    import json, sys
+    import jax, numpy as np
+    from repro import serving
+    from repro.launch.mesh import make_mesh
+    from repro.serving import workload
+    backend = sys.argv[1]
+    cls = type(jax.numpy.zeros(()))
+    real = cls.copy_to_host_async
+    spans = []                      # devices each started copy spans
+
+    def spy(self):
+        spans.append(len(self.sharding.device_set))
+        return real(self)
+    cls.copy_to_host_async = spy
+    reqs = workload.random_workload(np.random.default_rng(21), 24,
+                                    max_points=100)
+    one = serving.GeometryServer(backend=backend).serve(reqs)
+    one_spans = list(spans)
+    del spans[:]
+    serving.reset_stats()
+    with jax.set_mesh(make_mesh((4,), ("data",))):
+        mesh = serving.GeometryServer(backend=backend).serve(reqs)
+    same = sum(np.array_equal(np.asarray(a), np.asarray(b))
+               and np.array_equal(getattr(a, "mask", None),
+                                  getattr(b, "mask", None))
+               for a, b in zip(one, mesh, strict=True))
+    print(json.dumps({"one_spans": one_spans, "mesh_spans": spans,
+                      "launches": serving.stats["launches"],
+                      "prefetches": serving.stats["prefetches"],
+                      "same": same, "n": len(reqs)}))
+"""
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_mesh_flush_prefetches_sharded_outputs(backend):
+    """Under a 4-device mesh every launch's output is sharded over the
+    mesh, its copy back still starts at dispatch, and the results are
+    the single device's bit for bit.  Runs in a child process on four
+    virtual CPU devices, so the device-count flag stays out of this
+    one."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    out = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent(_MESH_PREFETCH), backend],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["prefetches"] == rec["launches"] > 1
+    # one copy per output leaf on either side, each over the whole mesh
+    assert len(rec["mesh_spans"]) == len(rec["one_spans"]) >= rec["launches"]
+    assert set(rec["one_spans"]) == {1} and set(rec["mesh_spans"]) == {4}
+    assert rec["same"] == rec["n"]
