@@ -18,7 +18,8 @@ companion paper's 2D/3D pipelines).  The batched forms
 ``chain_diag_batch`` / ``chain_apply_batch`` / ``chain_project_batch``
 take a packed (B, L, d) request batch with per-request folded parameters
 and are what ``repro.serving`` lowers a whole plan bucket to -- one
-launch per bucket.
+launch per bucket.  ``chain_project_instanced`` takes one resident point
+buffer under B folded chains: the instances of a mesh uploaded once.
 
 The fixed-point lane (``kernels.fixedpoint``) re-expresses the chain
 family on the M1's int16 Qm.n datapath: ``chain_diag_q`` /
@@ -39,7 +40,8 @@ from repro.kernels.fixedpoint import (chain_apply_batch_q, chain_apply_q,
                                       chain_diag_batch_q, chain_diag_q)
 from repro.kernels.flash_attention import attention, blockwise_attention
 from repro.kernels.matmul import chain_apply, chain_apply_batch, matmul, rotate2d
-from repro.kernels.projective import chain_project, chain_project_batch
+from repro.kernels.projective import (chain_project, chain_project_batch,
+                                      chain_project_instanced)
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.rope import rope, rope_tables
 from repro.kernels.ssd import ssd_intra
@@ -49,6 +51,6 @@ __all__ = [
     "scale", "translate", "vecadd", "attention", "blockwise_attention",
     "chain_apply", "chain_apply_batch", "chain_apply_batch_q",
     "chain_apply_q", "chain_diag_batch_q", "chain_diag_q", "chain_project",
-    "chain_project_batch", "matmul", "rotate2d", "rmsnorm",
+    "chain_project_batch", "chain_project_instanced", "matmul", "rotate2d", "rmsnorm",
     "rope", "rope_tables", "ssd_intra",
 ]

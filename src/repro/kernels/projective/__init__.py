@@ -1,3 +1,4 @@
-from repro.kernels.projective.ops import chain_project, chain_project_batch
+from repro.kernels.projective.ops import (chain_project, chain_project_batch,
+                                          chain_project_instanced)
 
-__all__ = ["chain_project", "chain_project_batch"]
+__all__ = ["chain_project", "chain_project_batch", "chain_project_instanced"]

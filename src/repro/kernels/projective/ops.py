@@ -84,3 +84,33 @@ def chain_project_batch(pts3: jnp.ndarray, h: jnp.ndarray, lo=None, hi=None,
                                          interpret=(b == "interpret"),
                                          block_rows=cfg.block_rows)
     return out, mask != 0
+
+
+def chain_project_instanced(x: jnp.ndarray, h: jnp.ndarray, lo=None,
+                            hi=None, *, backend: str | None = None):
+    """Instanced folded projective chains: B instances of one resident
+    point buffer, one launch.
+
+    ``x`` is the buffer in the kernel's layout, ``(rows, g)`` with
+    ``g = lane_group(d)`` (``GeometryServer.upload`` builds it: the flat
+    points zero-padded to ``util.resident_rows`` rows); ``h``
+    (B, d+1, d+1) / ``lo``/``hi`` (B, d) are the instances' folded
+    parameters.  Returns ``(projected (B, rows, g), inside (B, rows, g)
+    bool)`` in the buffer's layout, ``inside`` constant over each
+    point's d lanes.  On ``ref`` the oracle is ``ref.chain_project``
+    over the buffer's points under ``jax.vmap``, laid out the same way;
+    on ``pallas``/``interpret`` the ``chain_project_instanced_2d``
+    kernel."""
+    b = dispatch.resolve(backend)
+    h = jnp.asarray(h)
+    bsz, d = h.shape[0], h.shape[-1] - 1
+    lo, hi = _bounds(lo, hi, d, batch=(bsz,))
+    if b == "ref":
+        out, inside = jax.vmap(ref.chain_project, in_axes=(None, 0, 0, 0))(
+            x.reshape(-1, d), h, lo, hi)
+        shape = (bsz,) + x.shape
+        return out.reshape(shape), \
+            jnp.repeat(inside, d, axis=-1).reshape(shape)
+    out, mask = K.chain_project_instanced_2d(x, h, lo, hi,
+                                             interpret=(b == "interpret"))
+    return out, mask != 0

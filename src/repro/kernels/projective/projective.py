@@ -33,8 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.util import (SUBLANES, for_lane_chunks, lane_shift,
-                                pad_axis, stage_flat, stage_packed)
+from repro.kernels.util import (RESIDENT_TILE_ROWS, SUBLANES, for_lane_chunks,
+                                lane_group, lane_shift, pad_axis, stage_flat,
+                                stage_packed)
 
 
 def _proj_rows(h: jnp.ndarray, lane_coord: jnp.ndarray, d: int):
@@ -122,29 +123,68 @@ def chain_project_1d(flat: jnp.ndarray, h: jnp.ndarray, lo: jnp.ndarray,
     return out.reshape(-1)[:l], mask.reshape(-1)[:l]
 
 
+def _project_chunk(x, c_ref, wc_ref, g_ref, p_ref, *, d: int, g: int):
+    """The batch kernels' schedule over one (r, g) chunk of points:
+    2d-1 rolled MACs for the numerator and for w, the guarded divide,
+    the cull test and the AND-reduced mask.  Each parameter ref holds
+    g-lane rows (linear, perspective, same-point; p_ref: t, wt, lo,
+    hi), either one row per chunk row or one row for the whole chunk,
+    broadcast; the arithmetic of every lane is the same either way."""
+    acc = jnp.zeros_like(x) + p_ref[:, 0:g]
+    wacc = jnp.zeros_like(x) + p_ref[:, g:2 * g]
+    for i, delta in enumerate(range(-(d - 1), d)):
+        xr = lane_shift(x, delta)
+        acc = acc + xr * c_ref[:, i * g:(i + 1) * g]
+        wacc = wacc + xr * wc_ref[:, i * g:(i + 1) * g]
+    w_ok = wacc > 0.0
+    v = acc / jnp.where(w_ok, wacc, jnp.ones_like(wacc))
+    inl = jnp.where(w_ok & (v >= p_ref[:, 2 * g:3 * g])
+                    & (v <= p_ref[:, 3 * g:4 * g]),
+                    jnp.ones_like(v), jnp.zeros_like(v))
+    mask = jnp.ones_like(inl)
+    for i, delta in enumerate(range(-(d - 1), d)):
+        gm = g_ref[:, i * g:(i + 1) * g]
+        mask = mask * (lane_shift(inl, delta) * gm + (1.0 - gm))
+    return v, mask
+
+
 def _chain_project_batch_kernel(x_ref, c_ref, wc_ref, g_ref, p_ref, o_ref,
                                 m_ref, *, d: int, g: int):
-    def chunk(lanes):                   # p_ref rows (bm, 4g): t, wt, lo, hi
-        x = x_ref[:, lanes]                          # (bm, g) of bm requests
-        acc = jnp.zeros_like(x) + p_ref[:, 0:g]
-        wacc = jnp.zeros_like(x) + p_ref[:, g:2 * g]
-        for i, delta in enumerate(range(-(d - 1), d)):
-            xr = lane_shift(x, delta)
-            acc = acc + xr * c_ref[:, i * g:(i + 1) * g]
-            wacc = wacc + xr * wc_ref[:, i * g:(i + 1) * g]
-        w_ok = wacc > 0.0
-        v = acc / jnp.where(w_ok, wacc, jnp.ones_like(wacc))
-        inl = jnp.where(w_ok & (v >= p_ref[:, 2 * g:3 * g])
-                        & (v <= p_ref[:, 3 * g:4 * g]),
-                        jnp.ones_like(v), jnp.zeros_like(v))
-        mask = jnp.ones_like(inl)
-        for i, delta in enumerate(range(-(d - 1), d)):
-            gm = g_ref[:, i * g:(i + 1) * g]
-            mask = mask * (lane_shift(inl, delta) * gm + (1.0 - gm))
+    def chunk(lanes):                   # (bm, g): one chunk of bm requests
+        v, mask = _project_chunk(x_ref[:, lanes], c_ref, wc_ref, g_ref,
+                                 p_ref, d=d, g=g)
         o_ref[:, lanes] = v
         m_ref[:, lanes] = mask
 
     for_lane_chunks(x_ref.shape[1], g, chunk)
+
+
+def _chain_project_instanced_kernel(x_ref, c_ref, wc_ref, g_ref, p_ref, o_ref,
+                                    m_ref, *, d: int, g: int):
+    def chunk(rows):                    # (8, g): 8 rows of the shared mesh
+        v, mask = _project_chunk(x_ref[rows, :], c_ref, wc_ref, g_ref,
+                                 p_ref, d=d, g=g)
+        o_ref[rows, :] = v
+        m_ref[rows, :] = mask
+
+    for_lane_chunks(x_ref.shape[0], SUBLANES, chunk)
+
+
+def _proj_batch_rows(h, lo, hi, lane_coord, d: int, g: int):
+    """Each request's g-lane parameter rows, row-aligned: linear and
+    perspective coefficients ((B, (2d-1)g) each), the same-point rows
+    (one row, equal for every request) and t, wt, lo, hi ((B, 4g))."""
+    coef, wcoef, gmask = jax.vmap(
+        lambda hb: _proj_rows(hb, lane_coord, d))(h)   # (B, 2d-1, g) each
+    b = h.shape[0]
+    prow = jnp.concatenate([
+        h[:, d, :d][:, lane_coord],
+        jnp.broadcast_to(h[:, d, d][:, None], (b, g)),
+        lo.astype(h.dtype)[:, lane_coord],
+        hi.astype(h.dtype)[:, lane_coord]], axis=1)
+    return (coef.reshape(b, (2 * d - 1) * g),
+            wcoef.reshape(b, (2 * d - 1) * g),
+            gmask[:1].reshape(1, (2 * d - 1) * g), prow)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -168,17 +208,9 @@ def chain_project_batch_2d(pts3: jnp.ndarray, h: jnp.ndarray,
     if b == 0 or l == 0:
         return pts3, jnp.zeros((b, l), pts3.dtype)
     xp, lane_coord, bm, g = stage_packed(pts3, d, block_rows=block_rows)
-    hc = h.astype(pts3.dtype)
-    coef, wcoef, gmask = jax.vmap(
-        lambda hb: _proj_rows(hb, lane_coord, d))(hc)  # (B, 2d-1, g) each
-    coef = pad_axis(coef.reshape(b, (2 * d - 1) * g), 0, bm)
-    wcoef = pad_axis(wcoef.reshape(b, (2 * d - 1) * g), 0, bm)
-    grow = gmask[:1].reshape(1, (2 * d - 1) * g)       # same for every request
-    prow = pad_axis(jnp.concatenate([
-        hc[:, d, :d][:, lane_coord],
-        jnp.broadcast_to(hc[:, d, d][:, None], (b, g)),
-        lo.astype(pts3.dtype)[:, lane_coord],
-        hi.astype(pts3.dtype)[:, lane_coord]], axis=1), 0, bm)
+    coef, wcoef, grow, prow = _proj_batch_rows(h.astype(pts3.dtype), lo, hi,
+                                               lane_coord, d, g)
+    coef, wcoef, prow = (pad_axis(a, 0, bm) for a in (coef, wcoef, prow))
     out, mask = pl.pallas_call(
         functools.partial(_chain_project_batch_kernel, d=d, g=g),
         out_shape=[jax.ShapeDtypeStruct(xp.shape, pts3.dtype)] * 2,
@@ -195,4 +227,56 @@ def chain_project_batch_2d(pts3: jnp.ndarray, h: jnp.ndarray,
     )(xp, coef, wcoef, grow, prow)
     out = out[:b, :l * d].reshape(b, l, d)
     mask = mask[:b, :l * d].reshape(b, l, d)[:, :, 0]
+    return out, mask
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def chain_project_instanced_2d(x: jnp.ndarray, h: jnp.ndarray,
+                               lo: jnp.ndarray, hi: jnp.ndarray, *,
+                               interpret: bool = False):
+    """Instanced folded projective chains: one shared point buffer under
+    B folded chains, one launch.
+
+    ``x`` is a resident point buffer already in the kernel's layout:
+    ``(rows, g)`` with ``g = lane_group(d)`` and ``rows`` from
+    ``util.resident_rows``, the flat ``(N*d,)`` points zero-padded; ``h``
+    (B, d+1, d+1) / ``lo``/``hi`` (B, d) are each instance's folded
+    parameters.  The schedule is the batch kernel's (``_project_chunk``)
+    over chunks of 8 rows of the buffer, each row 128 whole points, with
+    instance b's parameter rows broadcast over the chunk.  The grid runs
+    tiles of rows outermost and instances innermost; the buffer's block
+    index ignores the instance, so each tile is read once for all B
+    instances, and a block stays one tile however long the mesh is.
+    Returns the projected points and a float mask, both (B, rows, g) in
+    the buffer's layout (the mask constant over each point's d lanes):
+    instance b's point i sits at flat words ``[i*d, (i+1)*d)`` of row b.
+    """
+    rows, g = x.shape
+    b, d = h.shape[0], h.shape[-1] - 1
+    if g != lane_group(d) or rows % SUBLANES:
+        raise ValueError(f"a {d}-D resident buffer is (rows, {lane_group(d)})"
+                         f" with rows a multiple of {SUBLANES}, got {x.shape}")
+    tr = min(rows, RESIDENT_TILE_ROWS)
+    lane_coord = jnp.arange(g) % d
+    coef, wcoef, grow, prow = _proj_batch_rows(h.astype(x.dtype), lo, hi,
+                                               lane_coord, d, g)
+    k = (2 * d - 1) * g
+
+    def per_instance(width):
+        return pl.BlockSpec((None, 1, width), lambda j, i: (i, 0, 0))
+
+    out, mask = pl.pallas_call(
+        functools.partial(_chain_project_instanced_kernel, d=d, g=g),
+        out_shape=[jax.ShapeDtypeStruct((b, rows, g), x.dtype)] * 2,
+        grid=(rows // tr, b),
+        in_specs=[
+            pl.BlockSpec((tr, g), lambda j, i: (j, 0)),    # shared points
+            per_instance(k),                               # linear rows
+            per_instance(k),                               # perspective rows
+            pl.BlockSpec((1, k), lambda j, i: (0, 0)),     # same-point rows
+            per_instance(4 * g),                           # t/wt/lo/hi rows
+        ],
+        out_specs=[pl.BlockSpec((None, tr, g), lambda j, i: (i, j, 0))] * 2,
+        interpret=interpret,
+    )(x, coef[:, None], wcoef[:, None], grow, prow[:, None])
     return out, mask

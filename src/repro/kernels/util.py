@@ -16,6 +16,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 SUBLANES = 8
+#: rows of ``lane_group(d)`` lanes in one block of a resident point
+#: buffer (``resident_rows``): 512 rows of 384 float32 lanes are 768 KiB
+RESIDENT_TILE_ROWS = 512
 
 
 def lane_shift(x: jnp.ndarray, delta: int) -> jnp.ndarray:
@@ -38,7 +41,8 @@ def for_lane_chunks(width: int, g: int, body) -> None:
     ``lane_group(d)``: a multiple of 128 (chunks are whole vregs) and of
     ``d`` (a chunk edge is a point edge, so a roll that wraps inside one
     chunk only moves lanes of another point onto lanes whose coefficient
-    is zero)."""
+    is zero).  The instanced kernel walks the rows of its block the same
+    way, ``SUBLANES`` rows a chunk."""
     def step(r, carry):
         body(pl.ds(pl.multiple_of(r * g, g), g))
         return carry
@@ -141,6 +145,18 @@ def stage_packed(pts3: jnp.ndarray, d: int, *, block_rows: int | None = None):
     xp = jnp.pad(flat, ((0, bp - b), (0, wr - l * d)))
     lane_coord = jnp.arange(g) % d
     return xp, lane_coord, bm, g
+
+
+def resident_rows(words: int, d: int) -> int:
+    """Rows of ``lane_group(d)`` lanes that hold a flat buffer of
+    ``words`` point words in the resident layout (``GeometryServer.upload``,
+    ``chain_project_instanced_2d``): whole points in every row, the
+    rows a multiple of ``SUBLANES``, and a whole number of
+    ``RESIDENT_TILE_ROWS`` blocks once they take more than one.  Fixed
+    by the buffer's own length, not by a bucket's size class."""
+    rows = round_up(cdiv(max(words, 1), lane_group(d)), SUBLANES)
+    return rows if rows <= RESIDENT_TILE_ROWS \
+        else round_up(rows, RESIDENT_TILE_ROWS)
 
 
 def pad_axis(x: jnp.ndarray, axis: int, multiple: int,

@@ -34,7 +34,7 @@ from repro.serving.bucketing import padded_length, waste_fraction
 from repro.serving.clock import (Clock, MonotonicClock, VirtualClock,
                                  percentile)
 from repro.serving.engine import (BatchPlan, BucketReport, FaultConfig,
-                                  GeometryServer, Projected,
+                                  GeometryServer, Projected, Resident,
                                   clear_plan_cache, get_batch_plan,
                                   reset_stats, stats)
 from repro.serving.errors import (CorruptionError, InjectedFault, LaunchError,
@@ -49,7 +49,8 @@ __all__ = [
     "BatchPlan", "BucketReport", "ChaosReport", "Clock", "CorruptionError",
     "FaultConfig", "FaultInjector", "GeometryServer", "InjectedFault",
     "LaunchError", "MonotonicClock", "Projected", "QueueFullError",
-    "RateLimitError", "RequestError", "SLOConfig", "Ticket", "TokenBucket",
+    "RateLimitError", "RequestError", "Resident", "SLOConfig", "Ticket",
+    "TokenBucket",
     "VirtualClock", "chain_for", "clear_plan_cache", "errors",
     "get_batch_plan", "is_error", "malform", "mixed_lane_workload",
     "padded_length", "percentile", "random_workload", "reset_stats",

@@ -44,6 +44,17 @@ float32-epsilon scale.  Results are bitwise deterministic for a fixed
 bucket shape, and padded rows never contaminate payload rows (points are
 row-independent).
 
+Resident meshes: ``upload(points)`` validates a float32 point set once,
+keeps a read-only host snapshot and puts the points on the device in
+the instanced kernel's flat layout, and returns a ``Resident`` handle.
+``submit(chain, handle)`` copies and packs nothing: projective requests
+on one handle, structure and backend bucket together, each an instance
+of the shared buffer, and run as one ``chain_project_instanced``
+launch.  Diag and matrix chains on a handle take the host-array path on
+its snapshot.  The projective kernel body
+and the folds are the host-array path's, so results on a handle are
+that path's bit for bit (``tests/test_resident.py``).
+
 Fixed-point serving: ``submit(..., qformat="q8.7")`` routes a request
 through the int16 Qm.n lane -- it buckets under the FORMAT (the dtype
 slot of the bucket key), packs as int16 words through the same
@@ -88,7 +99,8 @@ from repro.core import transform_chain as tc
 from repro.distributed import sharding
 from repro.kernels import (chain_apply_batch, chain_apply_batch_q,
                            chain_diag_batch, chain_diag_batch_q,
-                           chain_project_batch, dispatch, opcount)
+                           chain_project_batch, chain_project_instanced,
+                           dispatch, opcount, util)
 from repro.obs import metrics as obsm
 from repro.obs import trace as obst
 from repro.serving import bucketing
@@ -110,6 +122,12 @@ from repro.serving import errors as serrors
 #:                    right after the plan call; equals ``launches`` on
 #:                    a flush that needed no recovery (recovery's
 #:                    launches unpack at once and are not prefetched)
+#:   upload_bytes  -- host->device bytes each dispatched launch stages:
+#:                    its packed points (none on a resident bucket) and
+#:                    its folds, recovery's launches included
+#:   uploads       -- point sets put on the device by ``upload``
+#:   resident_requests -- requests served through flush() as instances
+#:                    of a handle (projective chains on a handle)
 #: fault-tolerance counters (all deterministic under a seeded injector;
 #: the chaos CI lane gates on them exactly):
 #:   rejected_requests  -- submissions refused with a typed RequestError
@@ -129,6 +147,7 @@ from repro.serving import errors as serrors
 _STAT_KEYS = ("plan_compiles", "plan_hits", "traces", "launches",
               "requests", "buckets", "shards",
               "payload_points", "padded_points", "prefetches",
+              "upload_bytes", "uploads", "resident_requests",
               "rejected_requests", "q_fallbacks", "launch_failures",
               "retries", "backend_fallbacks", "bisections",
               "recovered_requests", "failed_requests",
@@ -185,20 +204,23 @@ def _count_trace(kernel: str, backend: str, dtype: str, n: int) -> None:
                     backend=backend, dtype=dtype, n=n)
 
 
-def _per_device(body):
+def _per_device(body, shared_points: bool = False):
     """Run a bucket body once per device when a mesh is set.  A Mosaic
     kernel cannot be partitioned by the compiler, so the batch axis is
     split by ``shard_map`` over the mesh's fsdp axes (``_stage`` placed
     the rows and their folds that way) and each device traces the body
     at its own share of the rows.  Rows are independent and staging
     never changes arithmetic, so each row's result is the one a single
-    device computes."""
+    device computes.  ``shared_points``: the points are one resident
+    buffer, replicated on every device (``upload``), and only the folds
+    split."""
     def call(folded, pts3):
         mesh = sharding.ambient_mesh()
         if mesh is None or mesh.size == 1:
             return body(folded, pts3)
         spec = P(sharding.axis_names(mesh)[0])
-        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(spec, P() if shared_points else spec),
                              out_specs=spec, check_vma=False)(folded, pts3)
     return call
 
@@ -248,12 +270,18 @@ class BatchPlan:
     ``(projected (B,L,d), inside (B,L))``.  Fixed-point plans
     (``qformat`` set) take int16 Qm.n words -- each request's fold
     quantised by ``quantize.quantize_fold`` at pack time -- and return
-    int16."""
+    int16.  Instanced plans take the folds as one ``(B, words)`` array
+    (``_bind``), each row an instance of one resident buffer
+    (``Resident.device``, ``(rows, lane_group(d))``) taken in place of
+    ``pts3``, and return ``(projected, inside)`` in the buffer's
+    layout, ``(B, rows, lane_group(d))`` each; only projective chains
+    have them."""
     kind: str                      # "diag" | "matrix" | "projective"
     dim: int
     backend: str
     fn: typing.Callable
     qformat: str | None = None     # Qm.n name for fixed-point plans
+    instanced: bool = False        # takes a resident buffer
 
 
 def _compile_batch_q(structure: tuple, backend: str,
@@ -291,7 +319,8 @@ def _compile_batch_q(structure: tuple, backend: str,
                      fn=jax.jit(_per_device(body)), qformat=fmt.name)
 
 
-def _compile_batch(structure: tuple, backend: str) -> BatchPlan:
+def _compile_batch(structure: tuple, backend: str,
+                   instanced: bool = False) -> BatchPlan:
     dim, _ = structure
     kind = tc.plan_kind_of(structure)
 
@@ -331,19 +360,41 @@ def _compile_batch(structure: tuple, backend: str) -> BatchPlan:
             return chain_project_batch(pts3, h, lo, hi, backend=backend,
                                        config=cfg)
 
+    if instanced:
+        body = _instanced_body(dim, backend)
     return BatchPlan(kind=kind, dim=dim, backend=backend,
-                     fn=jax.jit(_per_device(body)))
+                     fn=jax.jit(_per_device(body, shared_points=instanced)),
+                     instanced=instanced)
+
+
+def _instanced_body(dim: int, backend: str):
+    """A resident projective bucket's body: the instanced kernel over
+    one shared buffer as uploaded, B folds laid side by side in one
+    array of rows (``_bind``): ``(H, lo, hi)`` each."""
+    def body(folded, x):
+        """Jitted projective transform + cull of B instances."""
+        (rows,) = folded
+        _count_trace("chain_project_instanced", backend, str(x.dtype),
+                     len(rows) * x.size // dim)
+        parts, at = [], 0
+        for shape in ((dim + 1, dim + 1), (dim,), (dim,)):
+            size = math.prod(shape)
+            parts.append(rows[:, at:at + size].reshape((len(rows),) + shape))
+            at += size
+        return chain_project_instanced(x, *parts, backend=backend)
+    return body
 
 
 def get_batch_plan(structure: tuple, backend: str,
-                   qname: str | None = None) -> BatchPlan:
+                   qname: str | None = None, *,
+                   instanced: bool = False) -> BatchPlan:
     """Mirrors ``transform_chain._get_plan`` deliberately: the two caches
     stay separate because they count into different stats domains (chain
     compiler vs serving engine) and compile different bodies (single
     folded pair vs stacked batch); keep their discipline in sync.
     ``qname`` selects the fixed-point lane (a distinct cached plan, as a
-    distinct dtype would be)."""
-    key = (structure, backend, qname)
+    distinct dtype would be); ``instanced`` the resident-buffer plan."""
+    key = (structure, backend, qname, instanced)
     plan = _BATCH_PLANS.get(key)
     trc = obst.active()
     if plan is None:
@@ -353,7 +404,8 @@ def get_batch_plan(structure: tuple, backend: str,
                         structure=_structure_tag(structure),
                         backend=backend, q=qname)
         plan = _compile_batch_q(structure, backend, qname) \
-            if qname is not None else _compile_batch(structure, backend)
+            if qname is not None \
+            else _compile_batch(structure, backend, instanced)
         _BATCH_PLANS[key] = plan
     else:
         stats["plan_hits"] += 1
@@ -365,6 +417,58 @@ def get_batch_plan(structure: tuple, backend: str,
 
 
 # -- the server --------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Resident:
+    """A point set that ``GeometryServer.upload`` put on the device once:
+    the handle ``submit`` takes in place of an array.
+
+    ``host`` is a read-only float32 snapshot of the points as uploaded
+    (the caller's array may change afterwards; results never do), kept
+    for identity chains and recovery.  ``device`` holds the same points
+    in the instanced kernel's layout: the flat ``(n*d,)`` words
+    zero-padded to ``(util.resident_rows(n*d, d), util.lane_group(d))``,
+    replicated over the mesh that was set at upload.  A handle compares
+    and hashes by identity, so it names its own bucket."""
+    host: np.ndarray
+    device: jax.Array
+
+    dtype = np.dtype(np.float32)
+
+    @property
+    def dim(self) -> int:
+        """Coordinates per point."""
+        return self.host.shape[-1]
+
+    @property
+    def n(self) -> int:
+        """Points in the set."""
+        return self.host.size // self.dim
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the device buffer."""
+        return self.device.nbytes
+
+    @property
+    def lpad(self) -> int:
+        """Points the device buffer holds, padding included: the length
+        of every instance a launch computes."""
+        return self.device.size // self.dim
+
+
+def _place_resident(host: np.ndarray) -> jax.Array:
+    """``host``'s points on the device in the resident layout; under a
+    mesh set with ``jax.set_mesh``, replicated on every device of it."""
+    d = host.shape[-1]
+    flat = np.zeros((util.resident_rows(host.size, d), util.lane_group(d)),
+                    np.float32)
+    flat.reshape(-1)[:host.size] = host.reshape(-1)
+    mesh = jax.sharding.get_mesh()
+    if mesh.empty or mesh.size == 1:
+        return jax.device_put(flat)
+    return jax.device_put(flat, NamedSharding(mesh, P()))
+
 
 @dataclasses.dataclass(frozen=True)
 class FaultConfig:
@@ -406,6 +510,7 @@ class _Pending:
     dequantize: bool = False       # float submitted -> float32 back
     q_fallback: bool = False       # q request rerouted to the float lane
     requant: quantize.QFormat | None = None   # int16 caller: requantise out
+    resident: Resident | None = None          # submitted on this handle
 
 
 class _FailedLaunch:
@@ -425,7 +530,7 @@ class _Launch:
     lpad: int
     plan: BatchPlan
     stacked: tuple
-    packed: np.ndarray
+    packed: np.ndarray | Resident  # the handle, on a resident bucket
     reqs: list
     report: "BucketReport"
     track: str = ""                # trace track: the bucket signature
@@ -471,6 +576,11 @@ def _bucket_track(structure: tuple, backend: str, dt: str,
                   lpad: int) -> str:
     """The trace track (Perfetto timeline) name of one plan bucket."""
     return f"{_structure_tag(structure)}|{backend}|{dt}|{lpad}"
+
+
+def _stack(folds: list) -> tuple:
+    """The requests' folds stacked part by part: (B, ...) each."""
+    return tuple(np.stack(part) for part in zip(*folds))
 
 
 def _prefetch(out) -> None:
@@ -564,6 +674,33 @@ class GeometryServer:
 
     # -- request intake ------------------------------------------------------
 
+    def upload(self, points) -> Resident:
+        """Put a point set on the device once; returns the handle that
+        ``submit``, ``submit_scene`` and ``AsyncGeometryServer.submit_async``
+        take in place of an array, for as many requests and flushes as the
+        caller likes.  The points pass the intake boundary's checks
+        (float32, (..., d), non-empty, finite) with its typed errors,
+        and are copied: the handle holds a read-only host snapshot and
+        the device buffer, so changing the caller's array afterwards
+        changes no result.  Under a mesh set with ``jax.set_mesh`` the
+        buffer is replicated on every device of it."""
+        with obst.active().span("resident.upload"):
+            shape = getattr(points, "shape", None)
+            if not shape:
+                raise errors.ShapeError(f"points are {shape}, not (n, d)")
+            host = np.array(points, copy=True)
+            errors.check_points(host, shape[-1])
+            if host.dtype != np.float32:
+                raise errors.DtypeError(
+                    f"resident points are float32, got {host.dtype}")
+            if self.fault_config.validate_finite \
+                    and not np.isfinite(host).all():
+                raise errors.NonFiniteError("points contain NaN/Inf")
+            host.flags.writeable = False
+            handle = Resident(host, _place_resident(host))
+            self._bump("uploads")
+        return handle
+
     def submit(self, chain: tc.TransformChain, points, *,
                qformat=None) -> int:
         """Queue one request; returns its ticket.  The next flush() returns
@@ -577,6 +714,11 @@ class GeometryServer:
         submissions, int16 for int16 ones.  Affine chains only --
         projective chains are rejected here, exactly as in
         ``TransformChain.apply``.
+
+        ``points`` may be a ``Resident`` handle from ``upload`` in place
+        of an array: nothing is copied or packed, and the request is
+        one instance of the resident buffer.  The q-format lane refuses
+        a handle (``DtypeError``).
 
         Submit is the isolation boundary: a malformed request (bad
         shape, empty point set, float64, NaN/Inf points or parameters, a
@@ -678,6 +820,9 @@ class GeometryServer:
         the packed lane could choke on later.  ``fold`` skips the
         ``chain.fold()`` recompute (scene-cached folds); every check
         downstream of the fold runs on the injected value unchanged."""
+        if isinstance(points, Resident):
+            return self._validate_resident(chain, points, qformat, ticket,
+                                           fold)
         cfg = self.fault_config
         # a real copy, not a view: the queue must be immune to callers
         # mutating their buffer between submit and flush
@@ -701,17 +846,7 @@ class GeometryServer:
                 and not np.isfinite(pts).all():
             raise errors.NonFiniteError(
                 "points contain NaN/Inf", ticket=ticket)
-        if not len(chain):
-            fold = None
-        else:
-            if fold is None:
-                fold = chain.fold()
-            if cfg.validate_finite:
-                # projective folds legitimately carry +/-inf cull bounds
-                parts = fold[:1] if chain.is_projective else fold
-                if not all(np.isfinite(np.asarray(f)).all() for f in parts):
-                    raise errors.NonFiniteError(
-                        "chain parameters fold to NaN/Inf", ticket=ticket)
+        fold = self._fold(chain, fold, ticket)
         q_fallback = False
         requant = None
         if fmt is not None and fold is not None \
@@ -737,6 +872,39 @@ class GeometryServer:
                         fold=fold, qformat=fmt, dequantize=dequant,
                         q_fallback=q_fallback, requant=requant)
 
+    def _fold(self, chain: tc.TransformChain, fold, ticket: int):
+        """The request's fold (``fold`` when injected), checked finite;
+        None for an identity chain."""
+        if not len(chain):
+            return None
+        if fold is None:
+            fold = chain.fold()
+        if self.fault_config.validate_finite:
+            # projective folds legitimately carry +/-inf cull bounds
+            parts = fold[:1] if chain.is_projective else fold
+            if not all(np.isfinite(np.asarray(f)).all() for f in parts):
+                raise errors.NonFiniteError(
+                    "chain parameters fold to NaN/Inf", ticket=ticket)
+        return fold
+
+    def _validate_resident(self, chain: tc.TransformChain, handle: Resident,
+                           qformat, ticket: int, fold) -> _Pending:
+        """The queue entry of a request on a handle: the points were
+        checked at ``upload``, so only the chain's dimension and the
+        fold remain, and nothing is copied.  A projective chain is an
+        instance of the device buffer; any other takes the host-array
+        path on the read-only snapshot (an identity chain's result is a
+        copy of it, the caller's to change)."""
+        if qformat is not None:
+            raise errors.DtypeError(
+                "the q-format lane packs int16 words on the host; a "
+                "resident handle holds float32 device words", ticket=ticket)
+        errors.check_points(handle.host, chain.dim, ticket=ticket)
+        points = handle.host if len(chain) else np.array(handle.host)
+        return _Pending(ticket, chain, points, handle.n,
+                        fold=self._fold(chain, fold, ticket),
+                        resident=handle if chain.is_projective else None)
+
     def serve(self, items, *, qformat=None) -> list:
         """Convenience: submit an iterable of (chain, points), then flush."""
         for chain, points in items:
@@ -751,6 +919,9 @@ class GeometryServer:
     # -- execution -----------------------------------------------------------
 
     def _bucket_key(self, p: _Pending, backend: str) -> tuple:
+        if p.resident is not None:
+            # the handle fixes the length: it takes the size class's slot
+            return (p.chain.structure, backend, "resident", p.resident)
         lpad = bucketing.padded_length(p.n, min_len=self.min_len,
                                        waste_cap=self.waste_cap)
         # fixed-point requests bucket under the FORMAT, not the submitted
@@ -784,8 +955,23 @@ class GeometryServer:
             for i, r in enumerate(reqs):
                 packed[i, :r.n] = r.points.reshape(-1, dim)
             folds = [r.fold for r in reqs]
-        stacked = tuple(np.stack(part) for part in zip(*folds))
-        return stacked, packed
+        return _stack(folds), packed
+
+    @staticmethod
+    def _bind(reqs: list[_Pending]):
+        """Bind a resident bucket: each instance's fold laid side by side
+        in one row, so a launch stages one small array (each argument a
+        plan call transfers costs its own host->device copy); the points
+        are the handle's device buffer, so nothing else is built."""
+        rows = np.concatenate([part.reshape(len(reqs), -1) for part in
+                               _stack([r.fold for r in reqs])], axis=1)
+        return (rows,), reqs[0].resident
+
+    def _assemble(self, reqs: list[_Pending], lpad: int, plan: BatchPlan):
+        """A bucket's launch operands: bound on a resident bucket,
+        packed otherwise."""
+        return self._bind(reqs) if plan.instanced \
+            else self._pack(reqs, lpad, plan)
 
     def _chunks(self, n_reqs: int, lpad: int) -> list[slice]:
         """Shard an oversized bucket along the batch axis."""
@@ -807,17 +993,23 @@ class GeometryServer:
         On a single device the arrays pass straight to the jitted plan,
         whose C++ argument path does the transfer -- an explicit
         ``device_put`` there is measurably pure python dispatch overhead
-        (it dominated the flush profile)."""
+        (it dominated the flush profile).  A resident bucket stages its
+        folds alone: its points are the handle's buffer, already on the
+        device (replicated over the mesh by ``upload``)."""
+        points = packed.device if isinstance(packed, Resident) else packed
         mesh = jax.sharding.get_mesh()
         if mesh.empty or mesh.size == 1:
-            return (stacked, packed)
+            return (stacked, points)
         fsdp, _ = sharding.axis_names(mesh)
         width = math.prod(mesh.shape[a] for a in fsdp)
-        pad = -len(packed) % width
+        pad = -len(stacked[0]) % width
 
         def place(x):
             x = np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
             return jax.device_put(x, NamedSharding(mesh, P(fsdp)))
+        if isinstance(packed, Resident):
+            return (tuple(place(x) for x in stacked),
+                    jax.device_put(points, NamedSharding(mesh, P())))
         return (tuple(place(x) for x in stacked), place(packed))
 
     # -- fault-injection hooks (no-ops without an injector) ------------------
@@ -845,8 +1037,8 @@ class GeometryServer:
         return self._stage(stacked, packed)
 
     def _count_launch(self, plan: BatchPlan, lpad: int, reqs: list,
-                      packed: np.ndarray, report: BucketReport,
-                      rung: int = 0, attempt: int = 0,
+                      stacked: tuple, packed: np.ndarray | Resident,
+                      report: BucketReport, rung: int = 0, attempt: int = 0,
                       track: str | None = None) -> None:
         """Bookkeeping for one DISPATCHED launch (called after the
         injector gate: a blocked attempt never reached the device).
@@ -863,6 +1055,10 @@ class GeometryServer:
             f"serve_bucket_{plan.kind}{'_q' if plan.qformat else ''}",
             nbytes)
         self._bump("launches")
+        # the folds, and the points unless they are the handle's own
+        # buffer (recovery stages a rebuilt one)
+        self._bump("upload_bytes", sum(f.nbytes for f in stacked) + (
+            0 if packed is reqs[0].resident else packed.nbytes))
         report.launches += 1
         trc = obst.active()
         if trc.enabled:
@@ -932,23 +1128,26 @@ class GeometryServer:
         # Build the launch list: one _Launch per shard.
         launches: list[_Launch] = []
         self.last_report = []
-        for (structure, bk, _dt, lpad), reqs in buckets.items():
+        for (structure, bk, _dt, size), reqs in buckets.items():
             qname = reqs[0].qformat.name if reqs[0].qformat is not None \
                 else None
+            resident = reqs[0].resident
+            lpad = size if resident is None else resident.lpad
             track = _bucket_track(structure, bk, _dt, lpad)
             bsid = trc.begin("bucket.assemble", track=track,
                              tickets=tuple(r.ticket for r in reqs),
                              rows=len(reqs), lpad=lpad) \
                 if trc.enabled else None
-            plan = get_batch_plan(structure, bk, qname)
+            plan = get_batch_plan(structure, bk, qname,
+                                  instanced=resident is not None)
             if trc.enabled:
-                psid = trc.begin("bucket.pack", track=track,
-                                 rows=len(reqs), lpad=lpad,
-                                 q=plan.qformat)
-                stacked, packed = self._pack(reqs, lpad, plan)
+                psid = trc.begin(
+                    "bucket.bind" if plan.instanced else "bucket.pack",
+                    track=track, rows=len(reqs), lpad=lpad, q=plan.qformat)
+                stacked, packed = self._assemble(reqs, lpad, plan)
                 trc.end(psid)
             else:
-                stacked, packed = self._pack(reqs, lpad, plan)
+                stacked, packed = self._assemble(reqs, lpad, plan)
             chunks = self._chunks(len(reqs), lpad)
             payload = sum(r.n for r in reqs)
             report = BucketReport(
@@ -962,8 +1161,8 @@ class GeometryServer:
                     structure=structure, qname=qname, backend=bk, lpad=lpad,
                     plan=plan,
                     stacked=jax.tree.map(lambda x: x[sl], stacked),
-                    packed=packed[sl], reqs=reqs[sl], report=report,
-                    track=track))
+                    packed=packed if resident is not None else packed[sl],
+                    reqs=reqs[sl], report=report, track=track))
             self.last_report.append(report)
             self.reports.append(report)
             self._bump("buckets")
@@ -971,6 +1170,8 @@ class GeometryServer:
                        len(chunks) - 1 if len(chunks) > 1 else 0)
             self._bump("payload_points", payload)
             self._bump("padded_points", len(reqs) * lpad)
+            if resident is not None:
+                self._bump("resident_requests", len(reqs))
             # the labeled serving dimensions (plan kind, backend,
             # dtype/qformat, padded size class) -- per-server only: the
             # aggregate view stays the flat counter set it always was
@@ -1010,8 +1211,9 @@ class GeometryServer:
                 if isinstance(staged, _FailedLaunch):
                     raise staged.err
                 self._check_injected(L.reqs, 0, 0)
-                self._count_launch(L.plan, L.lpad, L.reqs, L.packed, L.report,
-                                   rung=0, attempt=0, track=L.track)
+                self._count_launch(L.plan, L.lpad, L.reqs, L.stacked,
+                                   L.packed, L.report, rung=0, attempt=0,
+                                   track=L.track)
                 out = self._call(L.plan, staged, L.track)       # set 0
                 _prefetch(out)
                 outs.append(out)
@@ -1096,14 +1298,21 @@ class GeometryServer:
         the whole padded batch buffer for as long as the caller keeps
         any one result.  Projective launches return (points, mask);
         their results carry the per-point cull mask as
-        ``Projected.mask``."""
+        ``Projected.mask``.  An instanced launch's rows are in the
+        resident layout, each a flat buffer of point words with the mask
+        repeated over each point's d lanes, so a request's mask takes
+        every d-th word."""
         trc = obst.active()
         if plan.kind == "projective":
             host, mask = host
+            words = host.reshape(len(host), -1)
+            mask = mask.reshape(len(mask), -1)
+            step = plan.dim if plan.instanced else 1
             for i, r in enumerate(reqs):
                 results[r.ticket] = _projected(
-                    np.array(host[i, :r.n].reshape(r.points.shape)),
-                    np.array(mask[i, :r.n]
+                    np.array(words[i, :r.points.size]
+                             .reshape(r.points.shape)),
+                    np.array(mask[i, :r.n * step:step]
                              .reshape(r.points.shape[:-1])))
                 if trc.enabled:
                     trc.instant("request.resolve", ticket=r.ticket,
@@ -1161,7 +1370,8 @@ class GeometryServer:
         n_failures = 1 if depth == 0 else 0
         for ri, rung in enumerate(rungs):
             plan = L.plan if ri == 0 \
-                else get_batch_plan(L.structure, rung, L.qname)
+                else get_batch_plan(L.structure, rung, L.qname,
+                                    instanced=L.plan.instanced)
             start = n_failures if ri == 0 and depth == 0 else 0
             for attempt in range(start, cfg.max_launch_attempts):
                 if n_failures:
@@ -1174,12 +1384,18 @@ class GeometryServer:
                                  rung=rung, attempt=attempt) \
                     if trc.enabled else None
                 try:
-                    stacked, packed = self._pack(reqs, L.lpad, plan)
+                    stacked, packed = self._assemble(reqs, L.lpad, plan)
+                    if plan.instanced:
+                        # rebuild the buffer from the host snapshot: the
+                        # device copy may be what failed
+                        packed = Resident(packed.host,
+                                          _place_resident(packed.host))
                     dev = self._stage_attempt(plan, stacked, packed, reqs,
                                               ri, attempt)
                     self._check_injected(reqs, ri, attempt)
-                    self._count_launch(plan, L.lpad, reqs, packed, L.report,
-                                       rung=ri, attempt=attempt, track=rtrack)
+                    self._count_launch(plan, L.lpad, reqs, stacked, packed,
+                                       L.report, rung=ri, attempt=attempt,
+                                       track=rtrack)
                     out = self._call(plan, dev, rtrack)
                     self._unpack(plan, reqs, out, results, rtrack)
                 except Exception as e:

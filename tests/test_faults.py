@@ -286,6 +286,48 @@ class TestRecovery:
         rep = srv.last_report[0]
         assert rep.bisections == 3 and rep.failed_requests == 1
 
+    @pytest.mark.parametrize("backend,fault", [
+        ("ref", "flaky"), ("interpret", "backend"), ("ref", "poison")])
+    def test_resident_bucket_recovers_with_equal_results(self, backend,
+                                                        fault):
+        """A resident bucket whose launch the injector fails walks the
+        ladder like any other: rebuilt from the handle's host snapshot,
+        re-bound, and equal to a clean flush of the same requests on
+        the rung it lands on."""
+        from repro import graphics
+        cam = graphics.Camera(eye=(0.3, 0.4, 3.0), target=(0.0, 0.0, 0.0),
+                              up=(0.0, 1.0, 0.0), fov_y=0.9, aspect=1.0,
+                              near=0.1, far=10.0)
+        chains = [graphics.viewing_chain(
+            3, model=tc.TransformChain.identity(3).rotate(0.3 * i, axis=1),
+            camera=cam, viewport=graphics.Viewport(width=32.0, height=32.0))
+            for i in range(4)]
+        mesh = _pts(200, 3)
+        # the degraded bucket lands on ref: compare with ref's own flush
+        clean = _fresh(backend="ref" if fault == "backend" else backend)
+        handle = clean.upload(mesh)
+        want = clean.serve((c, handle) for c in chains)
+        inj = faults.FaultInjector(**{
+            "flaky": dict(flaky_tickets=frozenset({0, 1, 2, 3}),
+                          flaky_attempts=1),
+            "backend": dict(backend_tickets=frozenset({1})),
+            "poison": dict(poison_tickets=frozenset({2}))}[fault])
+        srv = _fresh(backend=backend, injector=inj,
+                     fault_config=_cfg(max_launch_attempts=2))
+        handle = srv.upload(mesh)
+        got = srv.serve((c, handle) for c in chains)
+        assert serving.stats["launch_failures"] >= 1
+        for i, (a, b) in enumerate(zip(got, want, strict=True)):
+            if fault == "poison" and i == 2:
+                assert isinstance(a, errors.LaunchError) and a.ticket == 2
+                continue
+            assert np.array_equal(a, b) and np.array_equal(a.mask, b.mask)
+        assert serving.stats["uploads"] == 1      # recovery is no upload
+        if fault == "backend":
+            assert srv.last_report[0].final_backend == "ref"
+        if fault == "poison":
+            assert serving.stats["recovered_requests"] == 3
+
     def test_failed_bucket_never_touches_its_neighbours(self):
         """Bucket isolation: a poisoned bucket recovers/fails alone; the
         other bucket completes with exactly its one clean launch."""

@@ -526,6 +526,41 @@ class TestEngineTracing:
                                      "attempt", "rows", "lpad", "dim",
                                      "itemsize", "hbm_bytes"}
 
+    def test_resident_flush_binds_and_stages_only_folds(self):
+        """A traced flush over a handle: one ``resident.upload`` span at
+        upload, ``bucket.bind`` in place of ``bucket.pack`` for the
+        resident bucket, and 88 staged bytes per 3-D projective request
+        (the 22 float32 fold words) beside an array request's points."""
+        from repro import graphics
+        cam = graphics.Camera(eye=(0.0, 0.5, 4.0), target=(0.0, 0.0, 0.0),
+                              up=(0.0, 1.0, 0.0), fov_y=0.8, aspect=1.5,
+                              near=0.1, far=10.0)
+        chains = [graphics.viewing_chain(
+            3, model=tc.TransformChain.identity(3).rotate(0.2 * i, axis=1),
+            camera=cam, viewport=graphics.Viewport(width=64.0, height=48.0))
+            for i in range(5)]
+        mesh = _pts(300, 3)
+        srv = _fresh(backend="ref")
+        trc = obs.Tracer(clock=VirtualClock())
+        with obs.installed(trc):
+            handle = srv.upload(mesh)
+            for c in chains:
+                srv.submit(c, handle)
+            srv.flush()
+        assert trc.count("resident.upload") == 1
+        assert trc.count("bucket.bind") == 1 and trc.count("bucket.pack") == 0
+        assert serving.stats["upload_bytes"] == 88 * 5
+        assert serving.stats["resident_requests"] == 5
+        assert serving.stats["uploads"] == srv.metrics.value("uploads") == 1
+        serving.reset_stats()
+        with obs.installed(trc):
+            srv.submit(chains[0], mesh)
+            srv.flush()
+        assert trc.count("bucket.pack") == 1
+        lpad = srv.last_report[0].lpad
+        assert serving.stats["upload_bytes"] == 88 + 4 * 3 * lpad
+        assert serving.stats["resident_requests"] == 0
+
     def test_bucket_tracks_and_labeled_dimensions(self):
         srv = _fresh(backend="ref")
         trc = obs.Tracer(clock=VirtualClock())

@@ -85,3 +85,18 @@ def test_kernel_compiles_for_v5e(name, d, one_chip):
              for s in _shapes(name, kind, d)]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("vertices,d", [(35_947, 3), (437_645, 3),
+                                        (4_096, 2)])
+def test_instanced_kernel_compiles_for_v5e(vertices, d, one_chip):
+    """The instanced projective kernel over a resident buffer of the
+    Bunny's and the Dragon's vertex counts, 8 instances: the block is
+    one tile of rows however long the mesh, so both compile."""
+    from repro.kernels import util
+    rows = util.resident_rows(vertices * d, d)
+    shapes = [(rows, util.lane_group(d)), (8, d + 1, d + 1), (8, d), (8, d)]
+    specs = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in shapes]
+    compiled = jax.jit(_project.chain_project_instanced_2d).lower(
+        *specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
