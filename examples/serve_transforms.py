@@ -3,7 +3,7 @@
 A miniature of the serving story end to end: a handful of *chain shapes*
 (sprite placement, 3D pose, a custom projective touch-up) each arrive many
 times with fresh parameters and differently-sized point sets.  The
-GeometryServer buckets them by structure + size class, so the whole
+GeometryServer buckets them by plan (dim, kind) + size class, so the whole
 workload runs in a handful of fused kernel launches -- and every result is
 checked against its own per-request ``TransformChain.apply``.
 
@@ -84,7 +84,7 @@ def main() -> None:
           f"launches ({stats['buckets']} plan buckets, "
           f"{stats['plan_compiles']} plans compiled)")
     for rep in server.last_report:
-        print(f"  bucket {rep.structure:<8} plan={rep.kind:<6} "
+        print(f"  bucket {rep.structure:<13} plan={rep.kind:<6} "
               f"lpad={rep.lpad:<4} requests={rep.requests:<3} "
               f"waste={rep.waste:.0%}")
 

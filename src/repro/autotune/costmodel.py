@@ -253,9 +253,9 @@ def grid_cost(requests: typing.Sequence[tuple[typing.Hashable, str, int, int]],
               itemsize: int = 4) -> CostEstimate:
     """Analytic cost of serving one workload under a candidate size grid.
 
-    ``requests`` is ``(structure_key, kind, d, n_points)`` per request --
+    ``requests`` is ``(plan_key, kind, d, n_points)`` per request --
     the shape of the workload, no point data needed.  The model replays
-    the engine's bucketing ((structure, padded length) -> one launch) and
+    the engine's bucketing ((plan key, padded length) -> one launch) and
     charges each bucket its packed byte volume plus the per-launch
     overhead: exactly the trade the grid knobs steer (a coarser grid means
     fewer launches but more padded bytes).
@@ -263,15 +263,15 @@ def grid_cost(requests: typing.Sequence[tuple[typing.Hashable, str, int, int]],
     from repro.kernels import opcount
     from repro.serving import bucketing
     buckets: dict[tuple, list[tuple[str, int, int]]] = {}
-    for skey, kind, d, n in requests:
+    for key, kind, d, n in requests:
         if n <= 0:
             continue
         lpad = bucketing.padded_length(n, min_len=min_len,
                                        waste_cap=waste_cap)
-        buckets.setdefault((skey, lpad), []).append((kind, d, n))
+        buckets.setdefault((key, lpad), []).append((kind, d, n))
     nbytes = 0
     flops = 0
-    for (_skey, lpad), reqs in buckets.items():
+    for (_key, lpad), reqs in buckets.items():
         kind, d, _ = reqs[0]
         nbytes += opcount.packed_chain_bytes(len(reqs), lpad, d,
                                              itemsize=itemsize, kind=kind)
@@ -282,11 +282,14 @@ def grid_cost(requests: typing.Sequence[tuple[typing.Hashable, str, int, int]],
 
 def workload_shape(reqs) -> list[tuple[typing.Hashable, str, int, int]]:
     """Project a ``[(chain, points), ...]`` workload to the shape tuples
-    ``grid_cost`` consumes (structure key, plan kind, dim, point count)."""
+    ``grid_cost`` consumes (plan key, plan kind, dim, point count); the
+    plan key is the engine's ``plan_identity``, the part of its bucket
+    key a chain decides."""
+    from repro.serving.engine import plan_identity
     out = []
     for chain, pts in reqs:
         n = int(pts.size // chain.dim)
-        out.append((chain.structure, chain.plan_kind, chain.dim, n))
+        out.append((plan_identity(chain), chain.plan_kind, chain.dim, n))
     return out
 
 

@@ -78,10 +78,10 @@ def print_summary(res: dict) -> None:
     st = res["stats"]
     min_len, cap, src = res["grid"]
     print(f"size grid: min_len={min_len} waste_cap={cap} ({src})")
-    print(f"{'bucket':<12} {'plan':<10} {'lpad':>5} {'reqs':>5} "
+    print(f"{'bucket':<14} {'plan':<10} {'lpad':>5} {'reqs':>5} "
           f"{'launches':>8} {'waste':>6}")
     for rep in res["report"]:
-        print(f"{rep.structure:<12} {rep.kind:<10} {rep.lpad:>5} "
+        print(f"{rep.structure:<14} {rep.kind:<10} {rep.lpad:>5} "
               f"{rep.requests:>5} {rep.launches:>8} {rep.waste:>6.1%}")
     print(f"\n{st['requests']} requests -> {st['launches']} launches "
           f"({st['buckets']} buckets, {st['shards']} extra shards); "

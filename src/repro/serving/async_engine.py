@@ -16,9 +16,9 @@ servers, mapped onto this repo's substrate:
      awaitable ``Ticket`` immediately; rejected ones raise a typed
      ``RequestError`` subclass with a stable code.
   2. **Schedule** -- admitted entries wait in per-bucket groups (keyed
-     exactly like the engine's plan buckets: structure + backend +
-     dtype/format + padded size class).  The flush policy couples the
-     max-wait deadline to the bucket fill fraction:
+     exactly like the engine's plan buckets: plan identity (dim, kind)
+     + backend + dtype/format + padded size class).  The flush policy
+     couples the max-wait deadline to the bucket fill fraction:
 
          due  <=>  fill >= 1  or  age >= max_wait_s * (1 - fill)
 

@@ -7,12 +7,16 @@ leaves the plan cache as the only amortisation.  This engine is the
 missing server loop, built from the paper's M1 execution discipline:
 
   1. **Bucket** -- pending requests group by
-     ``(TransformChain.structure, backend, dtype, padded_length)``.
-     Structure + backend pick the compiled plan (every request in a bucket
-     hits ONE cached batch plan -- the context-memory discipline: load a
-     context once, stream many operands through it); the size-bucketing
-     policy (``bucketing.padded_length``: power-of-two grid refined under
-     a waste cap) picks the padded length so padding waste per request
+     ``(dim, plan kind, backend, dtype, padded_length)``.  The plan
+     identity ``(dim, kind)`` (``plan_identity``) + backend pick the
+     compiled plan: a plan body reads nothing else of a chain, and each
+     request's folds are its own operands, so chains of different
+     structures but one ``(dim, kind)`` share a bucket and its launch
+     (every request in a bucket hits ONE cached batch plan -- the
+     context-memory discipline: load a context once, stream many
+     operands through it); the size-bucketing policy
+     (``bucketing.padded_length``: power-of-two grid refined under a
+     waste cap) picks the padded length so padding waste per request
      stays below the cap.
   2. **Pack** -- each bucket's variable-length point sets pad/stack into
      one lane-dense (B, L, d) batch, and each request folds host-side
@@ -48,7 +52,7 @@ Resident meshes: ``upload(points)`` validates a float32 point set once,
 keeps a read-only host snapshot and puts the points on the device in
 the instanced kernel's flat layout, and returns a ``Resident`` handle.
 ``submit(chain, handle)`` copies and packs nothing: projective requests
-on one handle, structure and backend bucket together, each an instance
+on one handle, plan identity and backend bucket together, each an instance
 of the shared buffer, and run as one ``chain_project_instanced``
 launch.  Diag and matrix chains on a handle take the host-array path on
 its snapshot.  The projective kernel body
@@ -107,7 +111,8 @@ from repro.serving import bucketing
 from repro.serving import errors as serrors
 
 #: serving statistics (observable by tests, benchmarks and the driver):
-#:   plan_compiles -- batched plans built (one per distinct structure+backend)
+#:   plan_compiles -- batched plans built (one per distinct plan identity
+#:                    (dim, kind) + backend + q-format + instanced)
 #:   plan_hits     -- plans served from the cache
 #:   traces        -- jit traces of plan bodies (new (B, L) shapes retrace;
 #:                    a seen shape must not)
@@ -116,6 +121,9 @@ from repro.serving import errors as serrors
 #:                    are not -- they never reached the device)
 #:   requests      -- requests served through flush()
 #:   buckets       -- plan buckets executed
+#:   bucket_structures -- distinct chain structures in each bucket, summed
+#:                    over buckets (over ``buckets``: how many structures
+#:                    share a plan bucket's launch)
 #:   shards        -- extra launches from splitting oversized buckets
 #:   payload_points / padded_points -- real vs padded points moved
 #:   prefetches    -- launches whose device->host copy ``flush`` started
@@ -145,7 +153,7 @@ from repro.serving import errors as serrors
 #:   queue_full_rejections  -- typed QueueFullError backpressure refusals
 #:   rate_limit_rejections  -- typed RateLimitError token-bucket refusals
 _STAT_KEYS = ("plan_compiles", "plan_hits", "traces", "launches",
-              "requests", "buckets", "shards",
+              "requests", "buckets", "bucket_structures", "shards",
               "payload_points", "padded_points", "prefetches",
               "upload_bytes", "uploads", "resident_requests",
               "rejected_requests", "q_fallbacks", "launch_failures",
@@ -284,14 +292,28 @@ class BatchPlan:
     instanced: bool = False        # takes a resident buffer
 
 
-def _compile_batch_q(structure: tuple, backend: str,
+def plan_identity(chain: tc.TransformChain) -> tuple[int, str]:
+    """What a batch plan depends on: the chain's dimension and plan kind.
+    A plan body reads nothing else of a chain -- each request's folds
+    are its own operands, of one shape per ``(dim, kind)`` -- so this
+    pair keys the plan cache, the bucket key (``_bucket_key``) and the
+    autotuner's launch count (``costmodel.workload_shape``)."""
+    return chain.dim, chain.plan_kind
+
+
+def _plan_tag(ident: tuple[int, str]) -> str:
+    """A plan identity as reports and traces print it, e.g. ``2D:matrix``."""
+    dim, kind = ident
+    return f"{dim}D:{kind}"
+
+
+def _compile_batch_q(ident: tuple[int, str], backend: str,
                      qname: str) -> BatchPlan:
     """Compile a fixed-point bucket executor: the same trace-time tuning
     consult as the float bodies, lowering to the int16 batch kernels with
     the format's fraction count as the requantising shift.  Projective
-    structures never get here (``submit`` rejects chain + qformat)."""
-    dim, _ = structure
-    kind = tc.plan_kind_of(structure)
+    chains never get here (``submit`` rejects chain + qformat)."""
+    dim, kind = ident
     fmt = quantize.as_qformat(qname)
 
     if kind == "diag":
@@ -319,10 +341,9 @@ def _compile_batch_q(structure: tuple, backend: str,
                      fn=jax.jit(_per_device(body)), qformat=fmt.name)
 
 
-def _compile_batch(structure: tuple, backend: str,
+def _compile_batch(ident: tuple[int, str], backend: str,
                    instanced: bool = False) -> BatchPlan:
-    dim, _ = structure
-    kind = tc.plan_kind_of(structure)
+    dim, kind = ident
 
     # Tuning-cache consult at trace time, mirroring the chain compiler:
     # the packed (B, L) shape is concrete under the jit trace, so the
@@ -385,34 +406,34 @@ def _instanced_body(dim: int, backend: str):
     return body
 
 
-def get_batch_plan(structure: tuple, backend: str,
+def get_batch_plan(ident: tuple[int, str], backend: str,
                    qname: str | None = None, *,
                    instanced: bool = False) -> BatchPlan:
-    """Mirrors ``transform_chain._get_plan`` deliberately: the two caches
-    stay separate because they count into different stats domains (chain
-    compiler vs serving engine) and compile different bodies (single
-    folded pair vs stacked batch); keep their discipline in sync.
-    ``qname`` selects the fixed-point lane (a distinct cached plan, as a
-    distinct dtype would be); ``instanced`` the resident-buffer plan."""
-    key = (structure, backend, qname, instanced)
+    """The cached batch plan of a plan identity ``(dim, kind)``
+    (``plan_identity``) on ``backend``.  It mirrors
+    ``transform_chain._get_plan``'s compiles/hits discipline; the two
+    caches stay separate because they count into different stats domains
+    (chain compiler vs serving engine) and compile different bodies
+    (single folded pair vs stacked batch).  ``qname`` selects the
+    fixed-point lane (a distinct cached plan, as a distinct dtype would
+    be); ``instanced`` the resident-buffer plan."""
+    key = (*ident, backend, qname, instanced)
     plan = _BATCH_PLANS.get(key)
     trc = obst.active()
     if plan is None:
         stats["plan_compiles"] += 1
         if trc.enabled:
             trc.instant("plan.compile", cache="serving",
-                        structure=_structure_tag(structure),
-                        backend=backend, q=qname)
-        plan = _compile_batch_q(structure, backend, qname) \
+                        plan=_plan_tag(ident), backend=backend, q=qname)
+        plan = _compile_batch_q(ident, backend, qname) \
             if qname is not None \
-            else _compile_batch(structure, backend, instanced)
+            else _compile_batch(ident, backend, instanced)
         _BATCH_PLANS[key] = plan
     else:
         stats["plan_hits"] += 1
         if trc.enabled:
             trc.instant("plan.hit", cache="serving",
-                        structure=_structure_tag(structure),
-                        backend=backend, q=qname)
+                        plan=_plan_tag(ident), backend=backend, q=qname)
     return plan
 
 
@@ -524,7 +545,7 @@ class _FailedLaunch:
 class _Launch:
     """One scheduled launch (a whole bucket, or one shard of it), with
     everything recovery needs to re-pack and re-dispatch its requests."""
-    structure: tuple
+    ident: tuple                   # the plan identity (dim, kind)
     qname: str | None
     backend: str                   # the rung this flush started on
     lpad: int
@@ -539,7 +560,7 @@ class _Launch:
 @dataclasses.dataclass
 class BucketReport:
     """Per-bucket accounting for one flush (the driver prints these)."""
-    structure: str                 # e.g. "2D:TSRT"
+    structure: str                 # the plan identity, e.g. "2D:matrix"
     kind: str                      # plan kind: diag | matrix | projective
     lpad: int                      # padded points per request
     requests: int
@@ -567,15 +588,10 @@ class BucketReport:
         return self.requests - self.launches
 
 
-def _structure_tag(structure: tuple) -> str:
-    dim, kinds = structure
-    return f"{dim}D:" + "".join(k for k, _ in kinds)
-
-
-def _bucket_track(structure: tuple, backend: str, dt: str,
+def _bucket_track(ident: tuple[int, str], backend: str, dt: str,
                   lpad: int) -> str:
     """The trace track (Perfetto timeline) name of one plan bucket."""
-    return f"{_structure_tag(structure)}|{backend}|{dt}|{lpad}"
+    return f"{_plan_tag(ident)}|{backend}|{dt}|{lpad}"
 
 
 def _stack(folds: list) -> tuple:
@@ -738,7 +754,7 @@ class GeometryServer:
         per request.
 
         Everything downstream is the ordinary serving lane: the same
-        (structure, backend, dtype, size-class) bucket key, the same
+        (dim, kind, backend, dtype, size-class) bucket key, the same
         packed kernels, the same typed validation boundary, the same
         ``qformat=`` fixed-point routing (the cached fold quantises
         through ``quantize.quantize_fold`` at pack time exactly like a
@@ -919,9 +935,11 @@ class GeometryServer:
     # -- execution -----------------------------------------------------------
 
     def _bucket_key(self, p: _Pending, backend: str) -> tuple:
+        """``(dim, kind, backend, dtype, padded length)``: requests that
+        run one plan body on operands of one shape share a launch."""
         if p.resident is not None:
             # the handle fixes the length: it takes the size class's slot
-            return (p.chain.structure, backend, "resident", p.resident)
+            return (*plan_identity(p.chain), backend, "resident", p.resident)
         lpad = bucketing.padded_length(p.n, min_len=self.min_len,
                                        waste_cap=self.waste_cap)
         # fixed-point requests bucket under the FORMAT, not the submitted
@@ -929,7 +947,7 @@ class GeometryServer:
         # pack into the same int16 batch (only unpack differs)
         dt = p.qformat.name if p.qformat is not None \
             else np.dtype(p.points.dtype).str
-        return (p.chain.structure, backend, dt, lpad)
+        return (*plan_identity(p.chain), backend, dt, lpad)
 
     def _pack(self, reqs: list[_Pending], lpad: int, plan: BatchPlan):
         """Pack a bucket: (B, lpad, d) zero-padded points + the stack of
@@ -1128,17 +1146,18 @@ class GeometryServer:
         # Build the launch list: one _Launch per shard.
         launches: list[_Launch] = []
         self.last_report = []
-        for (structure, bk, _dt, size), reqs in buckets.items():
+        for (dim, kind, bk, _dt, size), reqs in buckets.items():
+            ident = (dim, kind)
             qname = reqs[0].qformat.name if reqs[0].qformat is not None \
                 else None
             resident = reqs[0].resident
             lpad = size if resident is None else resident.lpad
-            track = _bucket_track(structure, bk, _dt, lpad)
+            track = _bucket_track(ident, bk, _dt, lpad)
             bsid = trc.begin("bucket.assemble", track=track,
                              tickets=tuple(r.ticket for r in reqs),
                              rows=len(reqs), lpad=lpad) \
                 if trc.enabled else None
-            plan = get_batch_plan(structure, bk, qname,
+            plan = get_batch_plan(ident, bk, qname,
                                   instanced=resident is not None)
             if trc.enabled:
                 psid = trc.begin(
@@ -1151,14 +1170,14 @@ class GeometryServer:
             chunks = self._chunks(len(reqs), lpad)
             payload = sum(r.n for r in reqs)
             report = BucketReport(
-                structure=_structure_tag(structure), kind=plan.kind,
+                structure=_plan_tag(ident), kind=plan.kind,
                 lpad=lpad, requests=len(reqs), payload_points=payload,
                 padded_points=len(reqs) * lpad, backend=bk,
                 final_backend=bk,
                 q_fallback_requests=sum(r.q_fallback for r in reqs))
             for sl in chunks:
                 launches.append(_Launch(
-                    structure=structure, qname=qname, backend=bk, lpad=lpad,
+                    ident=ident, qname=qname, backend=bk, lpad=lpad,
                     plan=plan,
                     stacked=jax.tree.map(lambda x: x[sl], stacked),
                     packed=packed if resident is not None else packed[sl],
@@ -1166,6 +1185,8 @@ class GeometryServer:
             self.last_report.append(report)
             self.reports.append(report)
             self._bump("buckets")
+            structures = len({r.chain.structure for r in reqs})
+            self._bump("bucket_structures", structures)
             self._bump("shards",
                        len(chunks) - 1 if len(chunks) > 1 else 0)
             self._bump("payload_points", payload)
@@ -1182,7 +1203,7 @@ class GeometryServer:
                      size_class=lpad).inc(len(reqs))
             if bsid is not None:
                 trc.end(bsid, kind=plan.kind, shards=len(chunks),
-                        payload_points=payload)
+                        payload_points=payload, structures=structures)
 
         # Phase 1 -- optimistic double-buffered dispatch (frame-buffer
         # set 0 / set 1): stage the first launch, then keep one launch
@@ -1370,7 +1391,7 @@ class GeometryServer:
         n_failures = 1 if depth == 0 else 0
         for ri, rung in enumerate(rungs):
             plan = L.plan if ri == 0 \
-                else get_batch_plan(L.structure, rung, L.qname,
+                else get_batch_plan(L.ident, rung, L.qname,
                                     instanced=L.plan.instanced)
             start = n_failures if ri == 0 and depth == 0 else 0
             for attempt in range(start, cfg.max_launch_attempts):

@@ -140,8 +140,8 @@ def test_q_lane_size_classes_match_float_lane():
         pq = srv.validate(chain, pts, qformat="q8.7")
         kf = srv._bucket_key(pf, backend)
         kq = srv._bucket_key(pq, backend)
-        # same structure, same padded size class...
-        assert kf[0] == kq[0] and kf[3] == kq[3]
-        assert kf[3] == bucketing.padded_length(n)
+        # same plan identity (dim, kind), same padded size class...
+        assert kf[:2] == kq[:2] == (2, "diag") and kf[4] == kq[4]
+        assert kf[4] == bucketing.padded_length(n)
         # ...different dtype lane: the format name, not the submit dtype
-        assert kq[2] == "q8.7" and kf[2] != kq[2]
+        assert kq[3] == "q8.7" and kf[3] != kq[3]

@@ -138,7 +138,7 @@ def test_deadline_expiry_flush_ordering():
     clk.advance(0.008)                  # both deadlines have passed
     assert eng.poll() == 2
     structures = [r.structure for r in eng.server.last_report]
-    assert structures == ["2D:TST", "3D:TRS"]
+    assert structures == ["2D:diag", "3D:matrix"]
 
     # and in the mirror order when arrival order flips
     eng2 = _fresh_async(backend="ref", clock=VirtualClock(),
@@ -149,7 +149,7 @@ def test_deadline_expiry_flush_ordering():
     eng2.clock.advance(0.008)
     assert eng2.poll() == 2
     assert [r.structure for r in eng2.server.last_report] \
-        == ["3D:TRS", "2D:TST"]
+        == ["3D:matrix", "2D:diag"]
 
 
 def test_poll_leaves_undue_groups_queued():

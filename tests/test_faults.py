@@ -328,6 +328,43 @@ class TestRecovery:
         if fault == "poison":
             assert serving.stats["recovered_requests"] == 3
 
+    @pytest.mark.parametrize("fault", ["flaky", "poison"])
+    def test_merged_bucket_recovers_both_structures(self, fault):
+        """A bucket holding two structures of one plan identity fails its
+        launch: recovery serves every request of both structures, and a
+        poison request whose recovery is exhausted resolves to its own
+        LaunchError."""
+        inj = faults.FaultInjector(**{
+            "flaky": dict(flaky_tickets=frozenset({5}), flaky_attempts=1),
+            "poison": dict(poison_tickets=frozenset({5}))}[fault])
+        srv = _fresh(backend="ref",
+                     fault_config=_cfg(max_launch_attempts=2), injector=inj)
+        other = tc.TransformChain.identity(2).scale(2.0, 0.5) \
+            .translate(-1.0, 0.25).scale(0.5, 1.5)
+        chains = [_chain2(), other] * 4
+        assert chains[0].structure != chains[1].structure
+        ptss = [_pts(8) for _ in chains]
+        for chain, p in zip(chains, ptss):
+            srv.submit(chain, p)
+        out = srv.flush()
+        (rep,) = srv.last_report
+        assert rep.structure == "2D:diag" and rep.requests == 8
+        assert serving.stats["bucket_structures"] == 2
+        for i, (chain, p) in enumerate(zip(chains, ptss)):
+            if fault == "poison" and i == 5:
+                assert isinstance(out[i], errors.LaunchError)
+                assert out[i].ticket == 5
+                continue
+            np.testing.assert_allclose(out[i], np.asarray(chain.apply(p)),
+                                       rtol=1e-6, atol=1e-6)
+        if fault == "flaky":
+            assert serving.stats["recovered_requests"] == 8
+            assert serving.stats["failed_requests"] == 0
+        else:
+            assert serving.stats["recovered_requests"] == 7
+            assert serving.stats["failed_requests"] == 1
+            assert rep.bisections == 3 and rep.failed_requests == 1
+
     def test_failed_bucket_never_touches_its_neighbours(self):
         """Bucket isolation: a poisoned bucket recovers/fails alone; the
         other bucket completes with exactly its one clean launch."""

@@ -570,7 +570,7 @@ class TestEngineTracing:
         tracks = {s.track for s in trc.spans if s.track}
         assert len(tracks) == 1
         track = tracks.pop()
-        assert "ref" in track                 # structure|backend|dtype|lpad
+        assert "ref" in track                 # plan|backend|dtype|lpad
         # the per-server labeled counter saw the bucket's rows
         kind, backend, dt, lpad = None, "ref", None, None
         for s in trc.spans:

@@ -122,15 +122,16 @@ class TestProfileAttribution:
         assert c["pred_flops"] > 0 and c["pred_m1_cycles"] > 0
 
     def test_counters_pinned(self, smoke):
-        # the launch tables and the model error are what they were when
-        # the prediction rode on the launch instant; the stream itself
-        # gained launch.call and the three unpack.* spans per launch
+        # buckets key on the plan identity (dim, kind): 26 launches where
+        # a bucket per chain structure made 40, moving the same padded
+        # bytes and flops; each launch has its launch.call and the three
+        # unpack.* spans
         _tracer, _server, prof = smoke
         assert prof.counters() == {
-            "events": 531, "spans": 347, "launches": 40, "kernels": 5,
-            "launch_buckets": 35, "hbm_bytes": 21872,
+            "events": 391, "spans": 249, "launches": 26, "kernels": 5,
+            "launch_buckets": 26, "hbm_bytes": 21872,
             "pred_hbm_bytes": 21872, "pred_flops": 22224,
-            "pred_m1_cycles": 4715, "byte_ratio_exact": 1}
+            "pred_m1_cycles": 4561, "byte_ratio_exact": 1}
 
     def test_fold_predicts_from_the_launch_shape(self, smoke):
         tracer, _server, prof = smoke

@@ -164,6 +164,35 @@ def test_two_meshes_beside_array_requests_in_one_flush(backend):
     assert srv.metrics.value("uploads") == 2
 
 
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_one_handle_is_one_bucket_whatever_the_structures(backend):
+    """Instances of two projective structures on one handle make one
+    bucket and one launch (a bucket keys on the plan identity and the
+    handle), a second handle its own: one launch per 8 instances, as a
+    frame of one structure makes, each equal to the host-array path."""
+    rng = np.random.default_rng(12)
+    meshes = [_mesh(rng, 900), _mesh(rng, 400)]
+    bare = np.eye(4, dtype=np.float32)
+    bare[3, 2] = 0.5                        # perspective, no cull
+    srv = _fresh(backend=backend)
+    handles = [srv.upload(m) for m in meshes]
+    reqs = []
+    for m in meshes:
+        for i in range(8):
+            reqs.append((_view(i) if i % 2 else tc.TransformChain.identity(3)
+                         .rotate(0.2 * i, axis=2).translate(0.0, 0.0, 1.0)
+                         .projective(bare), m))
+    assert len({c.structure for c, _ in reqs}) == 2
+    out = srv.serve((c, handles[0] if m is meshes[0] else handles[1])
+                    for c, m in reqs)
+    assert serving.stats["buckets"] == serving.stats["launches"] == 2
+    assert serving.stats["launches"] / serving.stats["requests"] == 0.125
+    assert serving.stats["bucket_structures"] == 4
+    assert serving.stats["resident_requests"] == 16
+    assert all(_same(a, b) for a, b in
+               zip(out, _host_path(backend, reqs), strict=True))
+
+
 @pytest.mark.parametrize("kind", ["diag", "matrix"])
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_diag_and_matrix_on_a_handle_take_the_array_path(backend, kind):

@@ -14,7 +14,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro import serving
+from repro import obs, serving
 from repro.core import transform_chain as tc
 from repro.kernels import opcount
 from repro.serving import bucketing, engine, workload
@@ -255,6 +255,159 @@ def test_bucketing_groups_by_structure_and_size():
     assert serving.stats["launches"] == 2
     assert {r.lpad for r in srv.last_report} == {32, 128}
     assert all(r.requests == 8 for r in srv.last_report)
+
+
+# ---------------------------------------------------------------------------
+# the bucket key is the plan identity (dim, kind), not the chain structure
+# ---------------------------------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+#: two requests to each of three size classes, per structure
+_SIZES = (7, 30, 120, 7, 30, 120)
+
+
+def _every_template(seed):
+    """(chain, points, qformat) requests: each ``workload.TEMPLATES``
+    entry (a 3-D template with a rotation under three rotation-axis
+    variants, the axis being part of a chain's structure), then two
+    diagonal structures on the q8.7 lane; each structure sends
+    ``_SIZES`` points, with fresh parameters per request."""
+    rng = np.random.default_rng(seed)
+    reqs = []
+    for dim, kinds in workload.TEMPLATES:
+        variants = 3 if dim == 3 and "R" in kinds else 1
+        by_structure: dict = {}
+        while len(by_structure) < variants or \
+                min(map(len, by_structure.values())) < len(_SIZES):
+            c = workload.chain_for(rng, dim, kinds)
+            if c.structure in by_structure or len(by_structure) < variants:
+                by_structure.setdefault(c.structure, []).append(c)
+        for chains in by_structure.values():
+            reqs += [(c, rng.standard_normal((n, dim)).astype(np.float32),
+                      None) for c, n in zip(chains, _SIZES)]
+    for kinds in ("TST", "TTSS"):
+        reqs += [(workload.chain_for(rng, 2, kinds),
+                  rng.uniform(-1, 1, (n, 2)).astype(np.float32), "q8.7")
+                 for n in _SIZES]
+    return reqs
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+def test_merged_buckets_are_bitwise_per_structure_buckets(backend):
+    """One flush of every template, rotation-axis variants and a q8.7
+    lane included, returns bit for bit what serving each structure
+    alone in its own flush returns: a bucket now holds every structure
+    of one plan identity, and each request still runs the same body on
+    operands of the same shapes.  Each structure sends two requests to
+    each size class, because on the CPU a one-row batch compiles to a
+    program of its own whose float contraction may differ by one
+    rounding (the module's equality contract), under either key."""
+    reqs = _every_template(16)
+    trc = obs.Tracer()
+    srv = _fresh_server(backend=backend)
+    with obs.installed(trc):
+        for chain, pts, qname in reqs:
+            srv.submit(chain, pts, qformat=qname)
+        merged = srv.flush()
+    groups: dict = {}
+    for i, (chain, _, qname) in enumerate(reqs):
+        groups.setdefault((chain.structure, qname), []).append(i)
+    alone = [None] * len(reqs)
+    for (_, qname), idx in groups.items():
+        one = serving.GeometryServer(backend=backend)
+        outs = one.serve([reqs[i][:2] for i in idx], qformat=qname)
+        for i, out in zip(idx, outs, strict=True):
+            alone[i] = out
+    for a, b in zip(merged, alone, strict=True):
+        assert type(a) is type(b) and a.dtype == b.dtype
+        assert np.array_equal(a, b)
+        if isinstance(a, serving.Projected):
+            assert np.array_equal(a.mask, b.mask)
+    # 19 structures (17 float, 2 q8.7) x 3 size classes, in 7 plan
+    # identities and lanes x 3 size classes
+    assert len(groups) == 19
+    assert srv.metrics.value("bucket_structures") == 57
+    assert srv.metrics.value("buckets") == 21
+    assert srv.metrics.value("q_fallbacks") == 0
+    assert {r.structure for r in srv.last_report} == {
+        "2D:diag", "2D:matrix", "2D:projective",
+        "3D:diag", "3D:matrix", "3D:projective"}
+    # a projective chain without a cull and one with it share a launch
+    letters = {i: "".join(k for k, _ in c.kinds)
+               for i, (c, _, _) in enumerate(reqs)}
+    shared = [s for s in trc.spans if s.name == "launch"
+              and {"TSRP", "MPC"} <= {letters[t] for t in s.tickets}]
+    assert len(shared) == 3
+
+
+def test_the_mixed_stream_pass_makes_126_launches(monkeypatch):
+    """The chip benchmark's ``mixed_stream.flush256`` pass (4 flushes of
+    256 requests over 11 templates): the server's own ``_bucket_key``
+    on the pass's shapes makes 126 buckets, where a bucket per chain
+    structure made 304.  Nothing is served."""
+    monkeypatch.syspath_prepend(REPO)
+    from chipbench import harness
+    from chipbench.families import template_stream
+    bench = harness.load_json(os.path.join(REPO, "BENCHMARK.json"))
+    _, config, traffic = harness.cell_parts(bench, "mixed_stream.flush256")
+    shapes = template_stream.shapes(config, traffic)
+    srv = serving.GeometryServer(backend="ref")
+    rng = np.random.default_rng(0)
+    per = traffic["per_flush"]
+    by_plan, by_structure = [], []
+    for f in range(0, len(shapes), per):
+        keys, structures = set(), set()
+        for dim, kinds, n, axes in shapes[f:f + per]:
+            chain, _ = template_stream.chain(rng, dim, kinds, axes)
+            key = srv._bucket_key(
+                srv.validate(chain, np.zeros((n, dim), np.float32)),
+                "pallas")
+            keys.add(key)
+            structures.add((chain.structure,) + key[2:])
+        by_plan.append(len(keys))
+        by_structure.append(len(structures))
+    assert by_structure == [76, 69, 80, 79] and sum(by_structure) == 304
+    assert by_plan == [33, 30, 31, 32]
+    assert sum(by_plan) / len(shapes) == 126 / 1024 == 0.123046875
+
+
+def test_two_structures_of_one_plan_identity_share_one_plan_compile():
+    rng = np.random.default_rng(61)
+    a = workload.chain_for(rng, 2, "TSRT")
+    b = workload.chain_for(rng, 2, "ASM")
+    assert a.structure != b.structure
+    assert engine.plan_identity(a) == engine.plan_identity(b) \
+        == (2, "matrix")
+    srv = _fresh_server(backend="ref")
+    srv.serve([(a, rng.standard_normal((30, 2)).astype(np.float32)),
+               (b, rng.standard_normal((100, 2)).astype(np.float32))])
+    # two size classes: two buckets, one compiled plan
+    assert serving.stats["buckets"] == 2
+    assert serving.stats["plan_compiles"] == 1
+    assert serving.stats["plan_hits"] == 1
+
+
+def test_two_structures_of_one_plan_identity_share_one_bucket():
+    rng = np.random.default_rng(62)
+    reqs = [(workload.chain_for(rng, 2, kinds),
+             rng.standard_normal((20, 2)).astype(np.float32))
+            for kinds in ("TST", "TTSS") * 4]
+    trc = obs.Tracer()
+    srv = _fresh_server(backend="ref")
+    with obs.installed(trc):
+        outs = srv.serve(reqs)
+    for (chain, pts), out in zip(reqs, outs, strict=True):
+        assert_diag_within_fma(
+            out, chain.apply(jnp.asarray(pts), backend="ref"), chain, pts)
+    assert serving.stats["buckets"] == serving.stats["launches"] == 1
+    assert serving.stats["bucket_structures"] == 2
+    assert srv.metrics.value("bucket_structures") == 2
+    (rep,) = srv.last_report
+    assert rep.structure == "2D:diag" and rep.requests == 8
+    (span,) = [s for s in trc.spans if s.name == "bucket.assemble"]
+    assert span.attrs["structures"] == 2
+    assert span.track == "2D:diag|ref|<f4|32"
 
 
 # ---------------------------------------------------------------------------
