@@ -79,7 +79,7 @@ def _check(reports) -> None:
     # workload's bucketing under the committed entry for ITS size class
     default = tcache.DEFAULTS["serving_grid"]
     for label, wl in (("smoke", search.smoke_workload()),
-                      ("bench64", search.bench_workload())):
+                      ("scale64", search.scale_workload())):
         n = search.workload_size_class_n(wl)
         entry = committed.get("serving_grid", reports[0].backend,
                               "float32", n)

@@ -86,7 +86,7 @@ class KernelConfig:
         return {k: v for k, v in d.items() if v is not None}
 
     def describe(self) -> str:
-        """Compact ``k=v`` summary for benchmark rows and reports."""
+        """Compact ``k=v`` summary for reports."""
         fields = self.key_fields()
         body = ",".join(f"{k}={v}" for k, v in sorted(fields.items()))
         return f"{self.source}({body})" if body else self.source

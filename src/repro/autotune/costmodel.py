@@ -42,7 +42,7 @@ STEP_OVERHEAD_US = 0.02
 #: effective streaming bandwidth for the predicted-time denominator.  The
 #: empirical timer runs wherever it runs; the model only needs candidate
 #: ORDERING to be right, so one conservative CPU-class figure is used for
-#: every backend (the TPU projection in benchmarks uses roofline.PEAKS).
+#: every backend.
 MODEL_BW = 20e9
 #: VMEM feasibility budget per core (v5e-class); candidates whose working
 #: set exceeds this are rejected before timing.
@@ -137,7 +137,8 @@ def chain_cost(n_points: int, d: int, kind: str,
     moves once in, once out (plus the mask pass for projective plans),
     plus the O(1) composed parameters.  ``itemsize`` defaults by kind: 4
     bytes on the float kinds, 2 on the ``_q`` (int16 Qm.n) kinds -- the
-    halved-byte prediction the fixed-point benchmark validates."""
+    halved-byte prediction ``tests/test_fixedpoint.py`` holds the lane
+    to."""
     from repro.kernels import opcount, util  # late: keep imports one-way
     kernel = _CHAIN_KERNELS[kind][0]
     itemsize = _kind_itemsize(kind, itemsize)
@@ -344,8 +345,8 @@ def m1_chain_cycles(kind: str, n_points: int, d: int) -> int:
 def perf_rows() -> list[analysis.PerfRow]:
     """The analytic predictions in the paper's table format (source
     ``model``), for the 8- and 64-element cases the paper publishes --
-    directly comparable against the emulator rows ``benchmarks.
-    paper_tables`` derives with source ``emulator``."""
+    directly comparable against the emulator rows
+    ``examples/paper_tables.py`` derives with source ``emulator``."""
     rows = []
     for routine in ("translation", "scaling"):
         for n in (8, 64):

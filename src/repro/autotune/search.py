@@ -112,7 +112,7 @@ class TrialResult:
 @dataclasses.dataclass(frozen=True)
 class TuneReport:
     """One tuning decision: the winner plus every timed trial (the CLI
-    prints these; benchmarks record tuned-vs-default from them)."""
+    prints these)."""
     kernel: str
     backend: str
     dtype: str
@@ -314,12 +314,12 @@ def tune_rmsnorm(backend: str, *, m: int, n: int, dtype: str = "float32",
 SMOKE_SEED = 1234             #: workload seed shared with --check re-runs
 SMOKE_REQUESTS = 24
 SMOKE_MAX_POINTS = 96
-#: the benchmark-scale workload (shared with benchmarks/autotune_bench.py:
-#: tune where you serve -- a grid tuned on small traffic does not
-#: transfer to large traffic, so both scales get their own cache entry)
-BENCH_SEED = 1904
-BENCH_REQUESTS = 64
-BENCH_MAX_POINTS = 1024
+#: the 64-request workload (tune where you serve -- a grid tuned on small
+#: traffic does not transfer to large traffic, so both scales get their
+#: own cache entry)
+SCALE_SEED = 1904
+SCALE_REQUESTS = 64
+SCALE_MAX_POINTS = 1024
 
 
 def workload_size_class_n(reqs) -> int:
@@ -336,11 +336,11 @@ def smoke_workload():
                                     templates=workload.TEMPLATES[:4])
 
 
-def bench_workload():
+def scale_workload():
     from repro.serving import workload
-    return workload.random_workload(seed=BENCH_SEED,
-                                    n_requests=BENCH_REQUESTS,
-                                    max_points=BENCH_MAX_POINTS)
+    return workload.random_workload(seed=SCALE_SEED,
+                                    n_requests=SCALE_REQUESTS,
+                                    max_points=SCALE_MAX_POINTS)
 
 
 def smoke_search(backend: str = "ref", *,
@@ -351,8 +351,8 @@ def smoke_search(backend: str = "ref", *,
     general 2D, projective 3D), the fixed-point twins of the affine two
     (int16 q8.7 -- cached under the format name as the dtype), plus the
     serving grid on BOTH seeded workloads (the tiny smoke mix and the
-    benchmark-scale 64-request mix -- each caches at its own size
-    class).  Returns the populated cache and the per-kernel reports."""
+    64-request mix -- each caches at its own size class).  Returns the
+    populated cache and the per-kernel reports."""
     cache = cache if cache is not None else TuningCache()
     reports = [
         tune_chain("chain_diag", backend, n_points=2048, d=3, cache=cache,
@@ -367,7 +367,7 @@ def smoke_search(backend: str = "ref", *,
                    dtype="q8.7", cache=cache, measure=measure, iters=iters),
         tune_serving_grid(smoke_workload(), backend, cache=cache,
                           measure=measure, iters=max(1, iters - 1)),
-        tune_serving_grid(bench_workload(), backend, cache=cache,
+        tune_serving_grid(scale_workload(), backend, cache=cache,
                           measure=measure, iters=max(2, iters - 1)),
     ]
     return cache, reports

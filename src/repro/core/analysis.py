@@ -3,8 +3,9 @@
 The paper's methodology: for each (algorithm, system) pair report cycles,
 total time at the system clock, elements/cycle, cycles/element, and speedup
 as the ratio of execution cycles.  These helpers compute the derived columns
-from cycle counts so benchmarks can print paper-format tables from either
-the published constants, our emulator, or our Intel models.
+from cycle counts so ``examples/paper_tables.py`` can print paper-format
+tables from either the published constants, our emulator, or our Intel
+models.
 """
 from __future__ import annotations
 

@@ -47,10 +47,6 @@ def scaling_cycles(cpu: str, n: int) -> int:
     return _loop_cycles(_SCALING[cpu], n)
 
 
-def time_us(cpu: str, cycles: int) -> float:
-    return cycles / CLOCK_MHZ[cpu]
-
-
 @dataclasses.dataclass(frozen=True)
 class Table5Row:
     algorithm: str

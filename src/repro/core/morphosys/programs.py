@@ -21,7 +21,8 @@ listing.  Our straight-line reconstruction (context stream for A rows +
 row-broadcast of B + MAC) is substantially faster because it overlaps context
 loads with only 3 wait slots and issues one MAC broadcast per cycle; the
 paper's count implies ~4 cycles per output element (fully serialised context
-reload + 2-cycle MAC).  ``benchmarks/paper_tables.py`` reports both numbers;
+reload + 2-cycle MAC).  ``examples/paper_tables.py`` reports both numbers
+(``tests/test_morphosys.py::test_paper_tables_example_matches_the_paper``);
 the published figures are used for the paper-fidelity speedup table and our
 reconstruction is reported alongside as the (faster) emulator-validated
 mapping.

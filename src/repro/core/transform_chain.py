@@ -81,7 +81,7 @@ _DIAG_KINDS = frozenset("TSA")
 _PROJ_KINDS = frozenset("PC")
 _AXES = {"x": 0, "y": 1, "z": 2}
 
-#: plan-cache / trace statistics (observable by tests and benchmarks):
+#: plan-cache / trace statistics (observable by tests):
 #:   compiles -- plans built (structure-level cache misses)
 #:   hits     -- plans served from the cache
 #:   traces   -- executions of a plan body under jax tracing (a cached plan
@@ -103,12 +103,12 @@ _PLAN_CACHE: dict[tuple, "Plan"] = {}
 
 
 def clear_plan_cache() -> None:
-    """Drop all compiled plans (benchmarks use this to measure cold cost)."""
+    """Drop all compiled plans (tests use this to start cold)."""
     _PLAN_CACHE.clear()
 
 
 def reset_stats() -> None:
-    """Zero every chain counter (benchmarks snapshot deltas from here)."""
+    """Zero every chain counter (tests snapshot deltas from here)."""
     for k in stats:
         stats[k] = 0
 

@@ -27,9 +27,9 @@ one write for the *whole* chain, versus one read+write per primitive under
 sequential dispatch.  Compiled plans are cached by chain structure +
 backend, so the serving hot path (same chain shape, fresh parameter values
 per request) neither re-folds nor retraces; ``TransformChain.apply_many``
-maps one cached plan over a leading batch axis in one launch.  See
-``benchmarks/PERF.md`` for the measured byte economy (the ``chain_*``
-benchmark rows).
+maps one cached plan over a leading batch axis in one launch.  The byte
+economy (one HBM pass for the whole chain) is held by
+``tests/test_transform_chain.py``.
 """
 from __future__ import annotations
 

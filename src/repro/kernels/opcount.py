@@ -10,7 +10,7 @@ the memory-bound model (inputs + outputs, parameters included) -- while a
 
 Records fire when the op *entry* executes, i.e. on every call on the
 eager path but only once (at trace time) under ``jax.jit``.  That is the
-intended use: tests and benchmarks compare eager sequential dispatch
+intended use: tests compare eager sequential dispatch
 against the fused chain path, whose single record is emitted by
 ``TransformChain.apply`` outside the jitted plan.
 """
@@ -67,7 +67,7 @@ def fused_chain_bytes(n_points: int, d: int, *, itemsize: int = 4,
     the int16 fixed-point lane (the lane's whole perf case: the same
     chain moves half the bytes).  The ONE formula shared by
     ``TransformChain``'s records, the autotune cost model, and the
-    fixed-point benchmark's f32-vs-q comparison."""
+    fixed-point lane's f32-vs-q comparison (``tests/test_fixedpoint.py``)."""
     return (chain_passes(kind) * n_points * d * itemsize
             + chain_param_words(d, kind) * itemsize)
 

@@ -1,7 +1,6 @@
 """Observability: deterministic tracing, typed metrics, exporters, a
 flight recorder, and the analysis layer built on top of them -- the
-cost-model-attributed profiler, the SLO burn-rate monitor, and the
-bench-trajectory trend analytics.
+cost-model-attributed profiler and the SLO burn-rate monitor.
 
 The layer's pieces (see ``docs/architecture.md`` sections 8-9):
 
@@ -25,8 +24,6 @@ The layer's pieces (see ``docs/architecture.md`` sections 8-9):
   * ``obs.slo``      -- multi-window burn-rate alerting over the
     latency / rejection error budgets, deterministic under a virtual
     clock, exported through ``prometheus_text``.
-  * ``obs.bench_history`` -- the committed ``BENCH_*.json`` records as
-    a time series; ``tools/bench_trend.py`` gates directional drift.
 
 Quickstart::
 

@@ -9,8 +9,8 @@ spans with ``recovery:<bucket>``, so Perfetto renders one timeline per
 plan bucket plus one per recovery ladder, with request-scoped spans on
 the main track.  Timestamps are clock seconds scaled to the format's
 microseconds; under a ``VirtualClock`` they are exact rationals of the
-seed, so the serialized file is byte-identical across runs -- the
-obs-smoke CI lane diffs two independent runs and the committed trace.
+seed, so the serialized file is byte-identical across runs --
+``tests/test_soak.py`` compares two fresh processes' exports.
 
 The Prometheus exposition is the standard text format, families sorted
 by name and label sets sorted by value tuple, so the output is also
@@ -111,8 +111,8 @@ def _fmt(v) -> str:
 def prometheus_text(*registries: MetricsRegistry) -> str:
     """Text exposition (version 0.0.4) of one or more registries.
     Families sort by exposition name and children by label values, so
-    equal registry states render byte-identically -- the obs-smoke lane
-    snapshots this output."""
+    equal registry states render byte-identically (``tests/test_soak.py``
+    compares two fresh processes' output)."""
     lines: list[str] = []
     fams = sorted(
         ((_name(reg.namespace, fam.name), fam)

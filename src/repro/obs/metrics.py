@@ -13,8 +13,8 @@ unifies them behind three typed instrument kinds:
   * ``Histogram`` -- sample distributions (request latency) whose
     quantiles come from the repo's ONE nearest-rank ``percentile``
     definition (defined here; ``serving.clock`` re-exports it), so
-    hand-pinned test values, engine telemetry, benchmark rows, and the
-    Prometheus exposition cannot disagree about what "p99" means.
+    hand-pinned test values, engine telemetry, and the Prometheus
+    exposition cannot disagree about what "p99" means.
 
 Instruments live in families keyed by name; a family declared with
 ``labels=(...)`` fans out into children per label-value combination

@@ -19,16 +19,16 @@ a ``repro.obs.trace`` span stream (PR 8) into
     The byte formulas are shared between ``kernels.opcount`` (what the
     engine records) and ``costmodel.packed_chain_cost`` (what it
     predicts), so the ratio is EXACTLY 1.0 by construction and any
-    drift is a real accounting bug; the profile-smoke CI lane gates
-    ``byte_ratio_exact=1``.
+    drift is a real accounting bug.
 
 Determinism contract: every COUNTER-valued quantity (span counts, launch
 counts, bytes, predictions, ratios) is bit-deterministic under a
-``serving.clock.VirtualClock`` -- ``counters()`` returns exactly those,
-and the benchmark rows gate on them.  Wall-clock quantities (the time
-columns of the report) are reported for humans and NEVER gated.
+``serving.clock.VirtualClock`` -- ``counters()`` returns exactly those
+(``tests/test_profile.py`` holds them across runs).  Wall-clock
+quantities (the time columns of the report) are reported for humans and
+NEVER gated.
 
-CLI (also reachable as ``benchmarks/run.py --profile``)::
+CLI::
 
     PYTHONPATH=src python -m repro.obs.profile --smoke
     PYTHONPATH=src python -m repro.obs.profile --spans dump.jsonl \
@@ -184,8 +184,8 @@ class Profile:
             and all(r == 1.0 for r in self.byte_ratios)
 
     def counters(self) -> dict:
-        """The bit-deterministic quantities (under a virtual clock):
-        what the profile benchmark rows gate on.  No wall time here."""
+        """The bit-deterministic quantities (under a virtual clock).  No
+        wall time here."""
         return {
             "events": self.n_events,
             "spans": self.n_spans,
@@ -290,8 +290,8 @@ def profile_smoke_workload(n_requests: int = 64, *, backend: str = "ref",
                            seed: int = 17, max_points: int = 48):
     """Serve one seeded mixed-lane workload under a traced virtual
     clock, from cold plan caches; returns ``(tracer, server)``.  The
-    self-driving mode of the CLI, the example, and the profile
-    benchmark all run exactly this, so their counters agree."""
+    self-driving mode of the CLI, the example, and the tests all run
+    exactly this, so their counters agree."""
     # late imports: obs sits BELOW serving in the import graph; only the
     # CLI entry points reach upward
     from repro.core import transform_chain as tc

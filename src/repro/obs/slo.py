@@ -23,7 +23,7 @@ monitor reads time ONLY through the injectable clock, so under a
 ``serving.clock.VirtualClock`` every burn-rate value, alert firing
 instant, and resolution instant is a bit-deterministic function of the
 arrival script -- ``tests/test_slo.py`` pins firing times to exact
-virtual seconds, and the ``slo_burn_smoke`` benchmark row gates them.
+virtual seconds.
 Alert state lives in a ``MetricsRegistry`` (``slo_*`` instruments), so
 the existing ``obs.export.prometheus_text`` exposes it unchanged.
 
@@ -227,7 +227,7 @@ class SLOMonitor:
 
     def counters(self) -> dict:
         """The deterministic summary (virtual-clock instants in µs, so
-        they survive a round trip through benchmark rows exactly)."""
+        they survive a round trip through text exactly)."""
         out = {}
         for obj, alert in sorted(self.alerts.items()):
             out[f"{obj}_alerts_fired"] = alert.fired

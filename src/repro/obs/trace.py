@@ -16,8 +16,8 @@ Design rules (each one is load-bearing):
     (``.now() -> float``).  Under a ``serving.clock.VirtualClock`` every
     timestamp, duration, and therefore the entire exported Chrome trace
     is a bit-deterministic function of the seeded workload: two runs
-    produce byte-identical JSON, which is what lets CI gate span counts
-    EXACTLY (the obs-smoke lane does).  The default is the process
+    produce byte-identical JSON (``tests/test_soak.py`` holds two fresh
+    processes to it).  The default is the process
     monotonic clock for real traffic.
   * **Flat stream, reconstructable trees.**  Spans append to one list in
     deterministic id order; parentage comes from a begin/end stack.
@@ -32,8 +32,8 @@ Design rules (each one is load-bearing):
     instrumentation hook in the engine guards with a single
     ``if trc.enabled:`` branch, so a disabled build pays one attribute
     load + one branch per hook and allocates nothing.  The acceptance
-    contract (pinned by ``tests/test_obs.py`` and the soak benchmark's
-    overhead row) is that counters with tracing disabled are
+    contract (pinned by ``tests/test_obs.py`` and
+    ``tests/test_soak.py``) is that counters with tracing disabled are
     bit-identical to a build that never imported this module.
   * **Flight recording.**  A tracer may carry a ``recorder`` sink
     (``obs.recorder.FlightRecorder``); every finished span is offered to
@@ -338,7 +338,7 @@ def install(tracer: Tracer | None) -> None:
 @contextlib.contextmanager
 def installed(tracer: Tracer | None):
     """Scoped install: the previous ambient tracer is restored on exit
-    (benchmarks trace one soak without leaking into the next)."""
+    (one traced run does not leak into the next)."""
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = tracer if tracer is not None else _NULL

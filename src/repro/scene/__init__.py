@@ -23,8 +23,8 @@ packed serving lane (float32 and Qm.n both) without weakening the
 engine's packed-vs-apply equality.  Counters (``scene.stats``: folds,
 cse_hits, cache_misses, refolds, dirtied) and trace instants
 (``scene.fold`` / ``scene.cse_hit`` / ``scene.refold``) make the CSE
-exactly gateable; see ``docs/scene_graph.md`` and
-``benchmarks/scene_bench.py``.
+exactly testable; see ``docs/scene_graph.md`` and
+``tests/test_scene.py::test_fold_count_equals_dirty_subtree``.
 """
 from repro.scene.cache import (FoldCache, REGISTRY, chain_digest,
                                path_digest, reset_stats, shared_cache,

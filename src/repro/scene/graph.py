@@ -26,8 +26,8 @@ Two mechanisms make that sound:
     dirty bit = an invalidated world digest).  The next resolution
     recomputes digests down the dirty path and folds ONLY nodes whose
     content digest is new to the cache: cost O(changed subtree), not
-    O(scene).  ``benchmarks/scene_bench.py`` gates "folds per frame ==
-    dirtied nodes" exactly.
+    O(scene).  ``tests/test_scene.py::test_fold_count_equals_dirty_subtree``
+    holds "folds per frame == dirtied nodes" exactly.
 
 Serving: ``GeometryServer.submit_scene(scene, node, points)`` submits a
 node's points through the cached world fold -- same buckets, same packed

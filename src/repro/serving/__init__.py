@@ -12,7 +12,8 @@ Fault tolerance (PR 6): ``submit`` rejects malformed requests with the
 typed ``serving.errors`` taxonomy; ``flush`` contains per-bucket launch
 failures behind a retry / backend-degradation / bisection ladder so no
 request is ever silently lost; ``serving.faults`` is the seeded
-fault-injection harness (``run_chaos_soak``) the chaos CI lane gates on.
+fault-injection harness (``run_chaos_soak``; ``tests/test_faults.py``
+holds it to zero lost requests).
 
 Continuous batching (PR 7): ``AsyncGeometryServer`` is the async
 front-end over the same engine -- ``submit_async`` returns awaitable
@@ -22,7 +23,7 @@ intake boundary with typed rejections, and a flush policy coupling the
 ``SLOConfig`` max-wait deadline to bucket fill decides when each plan
 bucket launches.  All timing flows through the injectable
 ``serving.clock.Clock`` (``VirtualClock`` = deterministic tests and the
-seeded soak benchmark; ``MonotonicClock`` = real traffic).
+seeded soak; ``MonotonicClock`` = real traffic).
 """
 from repro.serving import errors
 from repro.serving.admission import (AdmissionConfig, AdmissionController,

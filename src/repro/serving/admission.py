@@ -66,8 +66,8 @@ class TokenBucket:
     *clock* time (virtual or monotonic -- the bucket never reads a wall
     clock itself), and ``take`` spends one token per admitted request.
     Pure arithmetic on ``now`` values: two buckets fed the same take
-    timestamps make identical decisions, which is what lets the soak
-    benchmark gate rejection counts exactly."""
+    timestamps make identical decisions, which is what lets
+    ``tests/test_clock.py`` pin rejection counts exactly."""
     rate: float                    # tokens per second of clock time
     burst: float                   # bucket capacity (initial fill)
     tokens: float = None           # type: ignore[assignment]

@@ -31,16 +31,16 @@ servers, mapped onto this repo's substrate:
      flush results -- including typed ``LaunchError`` resolutions from
      the PR 6 recovery ladder, which runs unchanged under this front-end
      (the zero-lost-requests invariant is re-asserted through the async
-     path by ``tests/test_async_serving.py`` and the soak benchmark).
+     path by ``tests/test_async_serving.py`` and ``tests/test_soak.py``).
 
 **All timing flows through the injectable ``serving.clock.Clock``** --
 the engine never reads a wall clock.  Under a ``VirtualClock`` every
 scheduling decision, deadline expiry, latency sample, and admission
 refill is a deterministic function of the arrival script, which is what
 makes the scheduler *testable*: ``tests/test_clock.py`` pins flush
-ordering and p50/p99 values against hand-computed numbers, and the soak
-benchmark's latency telemetry sits in the exact-match CI gate.  Under
-the default ``MonotonicClock`` the same code serves real traffic.
+ordering and p50/p99 values against hand-computed numbers, and
+``tests/test_soak.py`` holds the soak's counters equal across processes.
+Under the default ``MonotonicClock`` the same code serves real traffic.
 
 Sync/async equivalence contract (``tests/test_async_serving.py``): the
 same seeded workload submitted while the clock is frozen and then

@@ -8,16 +8,17 @@ front-end (``serving.async_engine``) therefore never touches the wall
 clock -- it asks an injected ``Clock`` instead:
 
   * ``MonotonicClock`` -- production: ``time.monotonic`` / ``time.sleep``.
-  * ``VirtualClock``   -- tests and the seeded soak benchmark: time is a
+  * ``VirtualClock``   -- tests and the seeded soak: time is a
     number that moves only when the test (or the soak's arrival script)
     says so.  Every scheduling decision, deadline expiry, and latency
     sample becomes a deterministic function of the arrival script, so
     p50/p99 values can be pinned against hand-computed numbers and the
-    soak's latency telemetry sits in the exact-match CI gate.
+    soak's latency telemetry is equal from run to run
+    (``tests/test_soak.py``).
 
 ``percentile`` is the shared nearest-rank estimator -- the ONE
-definition, so hand-computed test values, engine telemetry, and
-benchmark rows cannot disagree about what "p99" means.  It lives in
+definition, so hand-computed test values and engine telemetry cannot
+disagree about what "p99" means.  It lives in
 ``repro.obs.metrics`` (the metrics layer's histograms consume it too)
 and is re-exported here unchanged for the serving-side callers.
 """

@@ -83,8 +83,8 @@ launches instead of losing B-1 good ones.  A request whose singleton
 launch still fails resolves to a typed ``LaunchError`` in its result
 slot: every submitted request resolves to a result or a typed error,
 never silence.  Every step is counted (``stats``/``BucketReport``) so
-recovery is CI-gateable on exact numbers; ``serving.faults`` injects
-deterministic faults to drive this machinery in tests and benchmarks.
+recovery is testable on exact numbers; ``serving.faults`` injects
+deterministic faults to drive this machinery in tests.
 """
 from __future__ import annotations
 
@@ -137,7 +137,7 @@ from repro.serving import errors as serrors
 #:   resident_requests -- requests served through flush() as instances
 #:                    of a handle (projective chains on a handle)
 #: fault-tolerance counters (all deterministic under a seeded injector;
-#: the chaos CI lane gates on them exactly):
+#: tests/test_faults.py::TestChaosSoak holds equal seeds to equal counts):
 #:   rejected_requests  -- submissions refused with a typed RequestError
 #:   q_fallbacks        -- q-lane requests rerouted to float32 because the
 #:                         error bound predicted int16 wrap
@@ -196,7 +196,7 @@ def reset_stats() -> None:
 
 
 def clear_plan_cache() -> None:
-    """Drop all compiled batch plans (benchmarks use this for cold timings)."""
+    """Drop all compiled batch plans (tests use this to start cold)."""
     _BATCH_PLANS.clear()
 
 

@@ -19,8 +19,8 @@ itself pass:
 
 Every decision is a pure function of ``(seed, ticket)`` -- roles come
 from ``np.random.default_rng([SALT, seed, ticket])`` -- so a soak run
-is bit-reproducible: the chaos CI lane gates on EXACT counter values,
-not "some faults happened".
+is bit-reproducible: ``tests/test_faults.py::TestChaosSoak`` asserts
+equal counters from equal seeds, not "some faults happened".
 
 ``run_chaos_soak`` is the harness: a seeded mixed-lane workload (all
 three plan kinds, float + q dtype lanes) served under injection, every
@@ -32,7 +32,6 @@ resolves to a verified result or a typed, ticket-named error.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import numpy as np
 
@@ -170,10 +169,9 @@ def malform(points: np.ndarray, mode: str) -> np.ndarray:
 
 @dataclasses.dataclass
 class ChaosReport:
-    """One soak run's full accounting.  Everything except ``elapsed_s``
-    (and the rates derived from it) is deterministic for a fixed (seed,
-    n_requests, rates, backend) -- the chaos CI lane gates on these
-    exact values via tools/check_bench.py."""
+    """One soak run's full accounting, deterministic for a fixed (seed,
+    n_requests, rates, backend); ``lost == 0`` and ``mismatches == 0``
+    are the invariants (tests/test_faults.py::TestChaosSoak)."""
     seed: int
     backend: str
     requests: int                  # well-formed submissions
@@ -193,23 +191,15 @@ class ChaosReport:
     q_fallbacks: int
     injected_launch_faults: int
     injected_corruptions: int
-    elapsed_s: float
     #: per-recovery-ladder flight-recorder post-mortems: one entry per
     #: recovery track, each the span/event dicts of that ladder's walk
     #: (deterministic under the soak's auto-installed virtual-clock
     #: tracer) -- a chaos failure in CI is debuggable from the report
     postmortems: list = dataclasses.field(default_factory=list)
 
-    @property
-    def recovered_rps(self) -> float:
-        """Recovered requests per second of soak wall time."""
-        return self.recovered_requests / max(self.elapsed_s, 1e-9)
-
     def counters(self) -> dict:
-        """The deterministic counter subset, name -> value (the shape
-        benchmark rows and CI gates consume)."""
+        """The counters, name -> value."""
         d = dataclasses.asdict(self)
-        d.pop("elapsed_s")
         d.pop("backend")
         d.pop("postmortems")
         return d
@@ -258,8 +248,8 @@ def run_chaos_soak(seed: int = 0, n_requests: int = 64, *,
                    qformat: str = "q8.7", malformed_every: int = 9,
                    flaky_rate: float = 0.06, backend_rate: float = 0.05,
                    corrupt_rate: float = 0.05, poison_rate: float = 0.03,
-                   fault_config: engine.FaultConfig | None = None,
-                   verify: bool = True) -> ChaosReport:
+                   fault_config: engine.FaultConfig | None = None
+                   ) -> ChaosReport:
     """Serve a seeded mixed-lane workload under seeded fault injection
     and account for every request.
 
@@ -270,11 +260,10 @@ def run_chaos_soak(seed: int = 0, n_requests: int = 64, *,
     defaults to "interpret" so the degradation ladder has a live rung
     below it ("ref") in every environment, including CPU CI.
 
-    With ``verify=True`` (the default -- benchmarks may disable it to
-    time the serving path alone) every resolved result is checked
-    against its per-request ``apply`` oracle and every failure slot must
-    be a ``LaunchError`` naming its own ticket; ``lost`` counts
-    submissions with neither, and the invariant is ``lost == 0``.
+    Every resolved result is checked against its per-request ``apply``
+    oracle and every failure slot must be a ``LaunchError`` naming its
+    own ticket; ``lost`` counts submissions with neither, and the
+    invariant is ``lost == 0``.
 
     Runs traced: if no tracer is installed, the soak installs its own
     (virtual clock at 0, so recovery post-mortems are a pure function of
@@ -289,13 +278,13 @@ def run_chaos_soak(seed: int = 0, n_requests: int = 64, *,
                 qformat=qformat, malformed_every=malformed_every,
                 flaky_rate=flaky_rate, backend_rate=backend_rate,
                 corrupt_rate=corrupt_rate, poison_rate=poison_rate,
-                fault_config=fault_config, verify=verify)
+                fault_config=fault_config)
     return _chaos_soak_traced(
         seed, n_requests, backend=backend, q_fraction=q_fraction,
         qformat=qformat, malformed_every=malformed_every,
         flaky_rate=flaky_rate, backend_rate=backend_rate,
         corrupt_rate=corrupt_rate, poison_rate=poison_rate,
-        fault_config=fault_config, verify=verify)
+        fault_config=fault_config)
 
 
 def _recovery_postmortems(trc) -> list:
@@ -310,7 +299,7 @@ def _recovery_postmortems(trc) -> list:
 
 def _chaos_soak_traced(seed, n_requests, *, backend, q_fraction, qformat,
                        malformed_every, flaky_rate, backend_rate,
-                       corrupt_rate, poison_rate, fault_config, verify):
+                       corrupt_rate, poison_rate, fault_config):
     cfg = fault_config or engine.FaultConfig()
     srv = engine.GeometryServer(
         backend=backend, fault_config=cfg,
@@ -322,7 +311,6 @@ def _chaos_soak_traced(seed, n_requests, *, backend, q_fraction, qformat,
                                            q_fraction=q_fraction,
                                            qformat=qformat)
     base = {k: engine.stats[k] for k in engine.stats}
-    t0 = time.perf_counter()
     rejected = malformed = 0
     submitted = []                 # (ticket, chain, pts, qname)
     for i, (chain, pts, qname) in enumerate(triples):
@@ -354,7 +342,6 @@ def _chaos_soak_traced(seed, n_requests, *, backend, q_fraction, qformat,
         except RequestError:
             rejected += 1          # the "reject" overflow policy
     results = srv.flush()
-    elapsed = time.perf_counter() - t0
 
     by_ticket = {}
     for (ticket, *_), res in zip(submitted, results):
@@ -370,7 +357,7 @@ def _chaos_soak_traced(seed, n_requests, *, backend, q_fraction, qformat,
             lost += 1
         else:
             resolved += 1
-            if verify and not _verify_one(chain, pts, qname, res, cfg):
+            if not _verify_one(chain, pts, qname, res, cfg):
                 mismatches += 1
     lost += len(submitted) - len(results) if len(results) < len(submitted) \
         else 0
@@ -393,5 +380,4 @@ def _chaos_soak_traced(seed, n_requests, *, backend, q_fraction, qformat,
         q_fallbacks=delta["q_fallbacks"],
         injected_launch_faults=srv.injector.injected_launch_faults,
         injected_corruptions=srv.injector.injected_corruptions,
-        elapsed_s=elapsed,
         postmortems=_recovery_postmortems(obst.active()))

@@ -1,11 +1,11 @@
-"""Synthetic mixed serving workloads (shared by tests, benchmarks, drivers).
+"""Synthetic mixed serving workloads (shared by tests, drivers, chipbench).
 
 A workload draws from a bounded pool of chain *structures* (the thing the
 engine buckets by) while every request gets fresh parameter values and a
 fresh variable-length point set -- the serving hot path the plan cache was
 built for: many requests, few structures.  ``timed`` is the one shared
-wall-clock helper, so the benchmark rows and the driver's printed numbers
-cannot measure differently.
+wall-clock helper, so the chip benchmark and the driver's printed
+numbers cannot measure differently.
 """
 from __future__ import annotations
 
@@ -45,7 +45,7 @@ TEMPLATES: tuple[tuple[int, str], ...] = (
 
 #: the affine-only template subset: structures the fixed-point (Qm.n)
 #: lane can execute (projective primitives P/C have no q form).  The ONE
-#: filter -- the fixed-point benchmark, its tests, and the example all
+#: filter -- the fixed-point lane's tests and its example all
 #: consume this, so a new projective-like template letter cannot leak
 #: unquantizable chains into any of them.
 AFFINE_TEMPLATES: tuple[tuple[int, str], ...] = tuple(
@@ -111,8 +111,8 @@ def random_workload(rng: np.random.Generator | int | None = None,
     Generator as ``rng``) and every draw -- structure parameters, point
     counts, point coordinates -- comes from that one stream, so two calls
     with the same seed and arguments produce bit-identical request mixes.
-    That is what makes tuned-vs-default benchmark comparisons apples to
-    apples (``benchmarks/autotune_bench.py`` relies on it)."""
+    That is what makes tuned-vs-default comparisons apples to apples
+    (``tests/test_autotune.py`` relies on it)."""
     if n_requests is None:
         raise ValueError("random_workload needs n_requests")
     if rng is None:

@@ -168,6 +168,27 @@ def test_grid_cost_replays_engine_bucketing(tuning_state):
         assert est.launches == serving.stats["launches"], (min_len, cap)
 
 
+@pytest.mark.parametrize("grid", ["default", "cached"])
+def test_grid_cost_exact_on_the_grid_a_server_resolves(grid, tuning_state):
+    """The model's launch count equals the launches of a server left to
+    resolve its own grid: the built-in default, or (autotune on) the
+    committed cache's tuned entry for the workload's size class."""
+    if grid == "cached":
+        _enable_with(TuningCache.load(tcache.DEFAULT_CACHE_PATH))
+    reqs = search.smoke_workload()
+    srv = serving.GeometryServer(backend="ref")
+    serving.reset_stats()
+    srv.serve(reqs)
+    assert srv.grid_source == grid
+    default = tcache.DEFAULTS["serving_grid"]
+    assert ((srv.min_len, srv.waste_cap)
+            == (default.grid_min_len, default.grid_waste_cap)) \
+        == (grid == "default")
+    est = costmodel.grid_cost(costmodel.workload_shape(reqs), srv.min_len,
+                              srv.waste_cap)
+    assert est.launches == serving.stats["launches"]
+
+
 def test_morphosys_cycles_match_emulator(tuning_state):
     """The closed-form cycle model reproduces the emulator (and through
     it the paper's published Table 5 numbers) for the 8/64-element

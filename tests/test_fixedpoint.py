@@ -428,6 +428,26 @@ class TestServingLane:
         assert nbytes == opcount.packed_chain_bytes(1, lpad, 2, itemsize=2,
                                                     kind="matrix")
 
+    def test_q8_7_moves_half_the_f32_bytes_on_the_same_requests(self):
+        # one seeded affine workload on both lanes: the same launches,
+        # each moving 2-byte words where the float lane moves 4-byte ones
+        reqs = workload.random_workload(seed=2203, n_requests=32,
+                                        max_points=512,
+                                        templates=AFFINE_TEMPLATES)
+
+        def serve(qformat):
+            serving.reset_stats()
+            with opcount.counting() as rec:
+                serving.GeometryServer(backend="ref").serve(
+                    reqs, qformat=qformat)
+            return (serving.stats["launches"],
+                    [b for name, b in rec if name.startswith("serve_")])
+
+        launches_f, bytes_f = serve(None)
+        launches_q, bytes_q = serve("q8.7")
+        assert launches_q == launches_f == len(bytes_f) == len(bytes_q) > 1
+        assert sorted(2 * b for b in bytes_q) == sorted(bytes_f)
+
     def test_identity_passes_and_empty_rejects(self):
         """PR 6: the q lane shares the submit boundary -- identity
         requests pass through, empty ones raise the typed error."""

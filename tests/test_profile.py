@@ -1,6 +1,5 @@
-"""Profiler + bench-trajectory tests: attribution exactness against the
-engine's own counters, cost-model prediction parity, span-stream
-round-trips, and the directional trend gate.
+"""Profiler tests: attribution exactness against the engine's own
+counters, cost-model prediction parity, and span-stream round-trips.
 
 The load-bearing invariants:
 
@@ -9,24 +8,17 @@ The load-bearing invariants:
     ``_count_launch``, the ONE place the counter moves);
   * every launch's observed/predicted HBM byte ratio is exactly 1.0 --
     ``kernels.opcount`` and ``autotune.costmodel`` share the byte
-    formula, so drift is an accounting bug, not noise;
-  * ``tools/bench_trend.py`` exits 0 on the real committed trajectory
-    and 1 on a synthetic worsened-counter fixture.
+    formula, so drift is an accounting bug, not noise.
 """
 import dataclasses
-import json
-import os
 
 import pytest
 
 from repro import obs, serving
 from repro.autotune import costmodel
 from repro.kernels import opcount
-from repro.obs import bench_history
 from repro.obs.profile import Profile, profile_smoke_workload
 from repro.serving import engine
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture()
@@ -187,96 +179,3 @@ class TestSpanStreamRoundTrip:
         obs.dump_span_stream(tracer, str(p1))
         obs.dump_span_stream(tracer, str(p2))
         assert p1.read_bytes() == p2.read_bytes()
-
-
-# ---------------------------------------------------------------------------
-# bench trajectory analytics
-# ---------------------------------------------------------------------------
-
-def _record(tmp_path, stamp, rows):
-    doc = {"timestamp": stamp, "smoke": True,
-           "rows": [dict(r, name=name) for name, r in rows.items()]}
-    path = tmp_path / f"BENCH_{stamp}.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-class TestBenchHistory:
-    def test_real_committed_trajectory_is_clean(self):
-        history = bench_history.load_history(
-            os.path.join(REPO_ROOT, "benchmarks"))
-        assert len(history) >= 2
-        assert bench_history.find_regressions(history) == []
-
-    def test_synthetic_regression_detected(self, tmp_path):
-        _record(tmp_path, "20260101_000000",
-                {"chain_smoke": {"launches": 10, "lost": 0,
-                                 "us_per_call": 5.0}})
-        _record(tmp_path, "20260102_000000",
-                {"chain_smoke": {"launches": 12, "lost": 0,
-                                 "us_per_call": 4.0}})
-        history = bench_history.load_history(str(tmp_path))
-        regs = bench_history.find_regressions(history)
-        assert len(regs) == 1
-        r = regs[0]
-        assert (r.row, r.field, r.prev, r.value) == \
-            ("chain_smoke", "launches", 10, 12)
-        assert "worsened" in str(r)
-
-    def test_improvement_and_new_rows_are_not_regressions(self, tmp_path):
-        _record(tmp_path, "20260101_000000",
-                {"a": {"launches": 10}})
-        _record(tmp_path, "20260102_000000",
-                {"a": {"launches": 8}, "b": {"launches": 99}})
-        history = bench_history.load_history(str(tmp_path))
-        assert bench_history.find_regressions(history) == []
-
-    def test_wallclock_fields_never_gate(self, tmp_path):
-        _record(tmp_path, "20260101_000000",
-                {"a": {"us_per_call": 1.0, "wall_s": 1.0}})
-        _record(tmp_path, "20260102_000000",
-                {"a": {"us_per_call": 9.0, "wall_s": 9.0}})
-        history = bench_history.load_history(str(tmp_path))
-        assert bench_history.find_regressions(history) == []
-
-    def test_series_and_drift_report(self, tmp_path):
-        _record(tmp_path, "20260101_000000", {"a": {"launches": 10}})
-        _record(tmp_path, "20260102_000000", {"a": {"launches": 8}})
-        history = bench_history.load_history(str(tmp_path))
-        assert bench_history.series(history, "a", "launches") == [
-            ("BENCH_20260101_000000.json", 10),
-            ("BENCH_20260102_000000.json", 8)]
-        report = bench_history.drift_report(history)
-        assert "| a | launches | 10 | 8 | IMPROVED |" in report
-
-
-class TestBenchTrendCLI:
-    def _main(self):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "bench_trend", os.path.join(REPO_ROOT, "tools",
-                                        "bench_trend.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.main
-
-    def test_exit_codes(self, tmp_path, capsys):
-        main = self._main()
-        # fewer than two records: nothing to compare
-        assert main(["--bench-dir", str(tmp_path)]) == 2
-        _record(tmp_path, "20260101_000000", {"a": {"launches": 10}})
-        _record(tmp_path, "20260102_000000", {"a": {"launches": 12}})
-        assert main(["--bench-dir", str(tmp_path)]) == 1
-        assert "REGRESSION" in capsys.readouterr().err
-        # real committed trajectory stays clean
-        assert main(["--bench-dir",
-                     os.path.join(REPO_ROOT, "benchmarks")]) == 0
-
-    def test_report_written(self, tmp_path):
-        main = self._main()
-        _record(tmp_path, "20260101_000000", {"a": {"launches": 10}})
-        _record(tmp_path, "20260102_000000", {"a": {"launches": 10}})
-        out = tmp_path / "drift.md"
-        assert main(["--bench-dir", str(tmp_path),
-                     "--report", str(out)]) == 0
-        assert "# Bench trajectory" in out.read_text()
