@@ -133,6 +133,10 @@ from repro.serving import errors as serrors
 #:   upload_bytes  -- host->device bytes each dispatched launch stages:
 #:                    its packed points (none on a resident bucket) and
 #:                    its folds, recovery's launches included
+#:   host_operands -- host arrays each dispatched launch stages, counted
+#:                    where ``upload_bytes`` counts their bytes: its one
+#:                    fold row, and its packed points (none on a resident
+#:                    bucket); each is its own host->device copy
 #:   uploads       -- point sets put on the device by ``upload``
 #:   resident_requests -- requests served through flush() as instances
 #:                    of a handle (projective chains on a handle)
@@ -155,7 +159,8 @@ from repro.serving import errors as serrors
 _STAT_KEYS = ("plan_compiles", "plan_hits", "traces", "launches",
               "requests", "buckets", "bucket_structures", "shards",
               "payload_points", "padded_points", "prefetches",
-              "upload_bytes", "uploads", "resident_requests",
+              "upload_bytes", "host_operands", "uploads",
+              "resident_requests",
               "rejected_requests", "q_fallbacks", "launch_failures",
               "retries", "backend_fallbacks", "bisections",
               "recovered_requests", "failed_requests",
@@ -212,25 +217,27 @@ def _count_trace(kernel: str, backend: str, dtype: str, n: int) -> None:
                     backend=backend, dtype=dtype, n=n)
 
 
-def _per_device(body, shared_points: bool = False):
-    """Run a bucket body once per device when a mesh is set.  A Mosaic
-    kernel cannot be partitioned by the compiler, so the batch axis is
-    split by ``shard_map`` over the mesh's fsdp axes (``_stage`` placed
-    the rows and their folds that way) and each device traces the body
-    at its own share of the rows.  Rows are independent and staging
-    never changes arithmetic, so each row's result is the one a single
-    device computes.  ``shared_points``: the points are one resident
-    buffer, replicated on every device (``upload``), and only the folds
-    split."""
-    def call(folded, pts3):
+def _per_device(body, kind: str, dim: int, shared_points: bool = False):
+    """A plan's jitted function: split the launch's fold row into its
+    parts (``_fold_parts``), then run the bucket body on them, once per
+    device when a mesh is set.  A Mosaic kernel cannot be partitioned
+    by the compiler, so the batch axis is split by ``shard_map`` over
+    the mesh's fsdp axes (``_stage`` placed the rows and their fold row
+    that way) and each device traces the body at its own share of the
+    rows.  Rows are independent and staging never changes arithmetic,
+    so each row's result is the one a single device computes.
+    ``shared_points``: the points are one resident buffer, replicated
+    on every device (``upload``), and only the folds split."""
+    def call(rows, pts3):
+        parts = _fold_parts(rows, kind, dim)
         mesh = sharding.ambient_mesh()
         if mesh is None or mesh.size == 1:
-            return body(folded, pts3)
+            return body(parts, pts3)
         spec = P(sharding.axis_names(mesh)[0])
         return jax.shard_map(body, mesh=mesh,
                              in_specs=(spec, P() if shared_points else spec),
-                             out_specs=spec, check_vma=False)(folded, pts3)
-    return call
+                             out_specs=spec, check_vma=False)(parts, pts3)
+    return jax.jit(call)
 
 
 class Projected(np.ndarray):
@@ -271,19 +278,22 @@ def _projected(points: np.ndarray, mask: np.ndarray) -> Projected:
 
 @dataclasses.dataclass(frozen=True)
 class BatchPlan:
-    """A compiled bucket executor: ``fn(folded_batch, pts3) -> out``
-    (jitted), where ``folded_batch`` stacks the bucket's host-folded
-    per-request parameters -- (s (B,d), t (B,d)), (A (B,d,d), t (B,d)),
-    or (H (B,d+1,d+1), lo (B,d), hi (B,d)).  Projective plans return
+    """A compiled bucket executor: ``fn(rows, pts3) -> out``
+    (jitted), where ``rows`` is the bucket's host-folded per-request
+    parameters as one ``(B, words)`` array (``_fold_row``): row i holds
+    request i's fold parts flattened side by side -- (s, t), (A, t) or
+    (H, lo, hi) -- which the plan splits on the device into
+    (B,d) + (B,d), (B,d,d) + (B,d) or (B,d+1,d+1) + (B,d) + (B,d)
+    (``_fold_parts``) before its body runs.  Projective plans return
     ``(projected (B,L,d), inside (B,L))``.  Fixed-point plans
-    (``qformat`` set) take int16 Qm.n words -- each request's fold
-    quantised by ``quantize.quantize_fold`` at pack time -- and return
-    int16.  Instanced plans take the folds as one ``(B, words)`` array
-    (``_bind``), each row an instance of one resident buffer
-    (``Resident.device``, ``(rows, lane_group(d))``) taken in place of
-    ``pts3``, and return ``(projected, inside)`` in the buffer's
-    layout, ``(B, rows, lane_group(d))`` each; only projective chains
-    have them."""
+    (``qformat`` set) take int16 Qm.n words, rows and points -- each
+    request's fold quantised by ``quantize.quantize_fold`` at pack time
+    -- and return int16.  Instanced plans take one resident buffer
+    (``Resident.device``, ``(rows, lane_group(d))``) in place of
+    ``pts3``, each row of folds an instance of it, and return
+    ``(projected, inside)`` in the buffer's layout,
+    ``(B, rows, lane_group(d))`` each; only projective chains have
+    them."""
     kind: str                      # "diag" | "matrix" | "projective"
     dim: int
     backend: str
@@ -305,6 +315,31 @@ def _plan_tag(ident: tuple[int, str]) -> str:
     """A plan identity as reports and traces print it, e.g. ``2D:matrix``."""
     dim, kind = ident
     return f"{dim}D:{kind}"
+
+
+def _fold_shapes(kind: str, dim: int) -> tuple:
+    """The shapes of one request's fold parts, in fold order."""
+    if kind == "diag":
+        return (dim,), (dim,)
+    if kind == "matrix":
+        return (dim, dim), (dim,)
+    return (dim + 1, dim + 1), (dim,), (dim,)
+
+
+def _fold_parts(rows, kind: str, dim: int) -> tuple:
+    """A launch's fold row (``_fold_row``) split on the device into its
+    parts, ``(B,) + shape`` each in fold order: the values the batch
+    kernels take, bit for bit.  Each part is a gather of its columns,
+    not a slice: XLA:CPU fuses a slice into the kernel's own loop and
+    may then contract its multiply-adds otherwise, where a gather
+    leaves the kernel's program as it is with the parts passed in."""
+    parts, at = [], 0
+    for shape in _fold_shapes(kind, dim):
+        size = math.prod(shape)
+        cols = rows[:, np.arange(at, at + size)]
+        parts.append(cols.reshape((len(rows),) + shape))
+        at += size
+    return tuple(parts)
 
 
 def _compile_batch_q(ident: tuple[int, str], backend: str,
@@ -338,7 +373,7 @@ def _compile_batch_q(ident: tuple[int, str], backend: str,
                                        backend=backend, config=cfg)
 
     return BatchPlan(kind=kind, dim=dim, backend=backend,
-                     fn=jax.jit(_per_device(body)), qformat=fmt.name)
+                     fn=_per_device(body, kind, dim), qformat=fmt.name)
 
 
 def _compile_batch(ident: tuple[int, str], backend: str,
@@ -384,25 +419,20 @@ def _compile_batch(ident: tuple[int, str], backend: str,
     if instanced:
         body = _instanced_body(dim, backend)
     return BatchPlan(kind=kind, dim=dim, backend=backend,
-                     fn=jax.jit(_per_device(body, shared_points=instanced)),
+                     fn=_per_device(body, kind, dim,
+                                    shared_points=instanced),
                      instanced=instanced)
 
 
 def _instanced_body(dim: int, backend: str):
     """A resident projective bucket's body: the instanced kernel over
-    one shared buffer as uploaded, B folds laid side by side in one
-    array of rows (``_bind``): ``(H, lo, hi)`` each."""
+    one shared buffer as uploaded, one fold an instance."""
     def body(folded, x):
         """Jitted projective transform + cull of B instances."""
-        (rows,) = folded
+        h, lo, hi = folded
         _count_trace("chain_project_instanced", backend, str(x.dtype),
-                     len(rows) * x.size // dim)
-        parts, at = [], 0
-        for shape in ((dim + 1, dim + 1), (dim,), (dim,)):
-            size = math.prod(shape)
-            parts.append(rows[:, at:at + size].reshape((len(rows),) + shape))
-            at += size
-        return chain_project_instanced(x, *parts, backend=backend)
+                     len(h) * x.size // dim)
+        return chain_project_instanced(x, h, lo, hi, backend=backend)
     return body
 
 
@@ -414,7 +444,7 @@ def get_batch_plan(ident: tuple[int, str], backend: str,
     ``transform_chain._get_plan``'s compiles/hits discipline; the two
     caches stay separate because they count into different stats domains
     (chain compiler vs serving engine) and compile different bodies
-    (single folded pair vs stacked batch).  ``qname`` selects the
+    (one fold vs a batch's fold rows).  ``qname`` selects the
     fixed-point lane (a distinct cached plan, as a distinct dtype would
     be); ``instanced`` the resident-buffer plan."""
     key = (*ident, backend, qname, instanced)
@@ -550,7 +580,7 @@ class _Launch:
     backend: str                   # the rung this flush started on
     lpad: int
     plan: BatchPlan
-    stacked: tuple
+    rows: np.ndarray               # its folds, one row a request
     packed: np.ndarray | Resident  # the handle, on a resident bucket
     reqs: list
     report: "BucketReport"
@@ -594,9 +624,22 @@ def _bucket_track(ident: tuple[int, str], backend: str, dt: str,
     return f"{_plan_tag(ident)}|{backend}|{dt}|{lpad}"
 
 
-def _stack(folds: list) -> tuple:
-    """The requests' folds stacked part by part: (B, ...) each."""
-    return tuple(np.stack(part) for part in zip(*folds))
+def _fold_row(folds: list, dtype) -> np.ndarray:
+    """The requests' folds as one ``(B, words)`` array of the lane's
+    dtype: row i holds request i's fold parts flattened side by side in
+    fold order, which ``_fold_parts`` takes apart on the device.  A
+    launch then stages its folds as one host array, where each array a
+    plan call transfers costs its own host->device copy.  Every part of
+    a fold already has the lane's dtype, so no value is cast."""
+    rows = np.empty((len(folds), sum(np.size(p) for p in folds[0])), dtype)
+    at = 0
+    for part in zip(*folds):
+        shape = np.shape(part[0])
+        size = math.prod(shape)
+        # a view: splitting the slice's contiguous last axis copies nothing
+        rows[:, at:at + size].reshape((len(folds),) + shape)[...] = part
+        at += size
+    return rows
 
 
 def _prefetch(out) -> None:
@@ -950,9 +993,9 @@ class GeometryServer:
         return (*plan_identity(p.chain), backend, dt, lpad)
 
     def _pack(self, reqs: list[_Pending], lpad: int, plan: BatchPlan):
-        """Pack a bucket: (B, lpad, d) zero-padded points + the stack of
-        each request's host-folded parameters (the same numpy fold
-        ``TransformChain.apply`` runs, so the folds are bit-identical).
+        """Pack a bucket: (B, lpad, d) zero-padded points + each
+        request's host-folded parameters in one row array (the same numpy
+        fold ``TransformChain.apply`` runs, so the folds are bit-identical).
         Fixed-point buckets pack int16 Qm.n words -- float submissions
         quantise here, and each fold quantises through the same
         ``quantize.quantize_fold`` the chain compiler's q lane uses.
@@ -973,17 +1016,15 @@ class GeometryServer:
             for i, r in enumerate(reqs):
                 packed[i, :r.n] = r.points.reshape(-1, dim)
             folds = [r.fold for r in reqs]
-        return _stack(folds), packed
+        return _fold_row(folds, packed.dtype), packed
 
     @staticmethod
     def _bind(reqs: list[_Pending]):
-        """Bind a resident bucket: each instance's fold laid side by side
-        in one row, so a launch stages one small array (each argument a
-        plan call transfers costs its own host->device copy); the points
-        are the handle's device buffer, so nothing else is built."""
-        rows = np.concatenate([part.reshape(len(reqs), -1) for part in
-                               _stack([r.fold for r in reqs])], axis=1)
-        return (rows,), reqs[0].resident
+        """Bind a resident bucket: its folds in one row array, as
+        ``_pack`` stages them; the points are the handle's device
+        buffer, so nothing else is built."""
+        handle = reqs[0].resident
+        return _fold_row([r.fold for r in reqs], handle.dtype), handle
 
     def _assemble(self, reqs: list[_Pending], lpad: int, plan: BatchPlan):
         """A bucket's launch operands: bound on a resident bucket,
@@ -1001,13 +1042,14 @@ class GeometryServer:
                 for i in range(0, n_reqs, rows)]
 
     @staticmethod
-    def _stage(stacked, packed):
+    def _stage(rows, packed):
         """Host->device staging for one launch (the set-1 DMA).  When a
         device mesh is set (``jax.set_mesh``) the packed batch AND its
-        row-aligned folds are placed sharded over the mesh's fsdp axes,
-        so one launch spans the mesh and each device runs the kernel on
-        its own rows (``_per_device``).  The batch pads with zero rows to
-        a multiple of the fsdp width; unpack reads only the real rows.
+        fold row, one row a request, are placed sharded over the mesh's
+        fsdp axes, so one launch spans the mesh and each device runs the
+        kernel on its own rows (``_per_device``).  The batch pads with
+        zero rows to a multiple of the fsdp width; unpack reads only the
+        real rows.
         On a single device the arrays pass straight to the jitted plan,
         whose C++ argument path does the transfer -- an explicit
         ``device_put`` there is measurably pure python dispatch overhead
@@ -1017,18 +1059,18 @@ class GeometryServer:
         points = packed.device if isinstance(packed, Resident) else packed
         mesh = jax.sharding.get_mesh()
         if mesh.empty or mesh.size == 1:
-            return (stacked, points)
+            return (rows, points)
         fsdp, _ = sharding.axis_names(mesh)
         width = math.prod(mesh.shape[a] for a in fsdp)
-        pad = -len(stacked[0]) % width
+        pad = -len(rows) % width
 
         def place(x):
             x = np.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
             return jax.device_put(x, NamedSharding(mesh, P(fsdp)))
         if isinstance(packed, Resident):
-            return (tuple(place(x) for x in stacked),
+            return (place(rows),
                     jax.device_put(points, NamedSharding(mesh, P())))
-        return (tuple(place(x) for x in stacked), place(packed))
+        return (place(rows), place(packed))
 
     # -- fault-injection hooks (no-ops without an injector) ------------------
 
@@ -1040,7 +1082,7 @@ class GeometryServer:
             self.injector.before_launch(
                 tuple(r.ticket for r in reqs), rung_index, attempt)
 
-    def _stage_attempt(self, plan: BatchPlan, stacked, packed, reqs: list,
+    def _stage_attempt(self, plan: BatchPlan, rows, packed, reqs: list,
                        rung_index: int, attempt: int):
         """Staging with the corruption hook: the injector may flip words
         in the packed operand buffer on its way to the device.  Only
@@ -1052,10 +1094,10 @@ class GeometryServer:
                 and plan.kind != "projective":
             packed = inj.corrupt_staging(
                 packed, tuple(r.ticket for r in reqs), rung_index, attempt)
-        return self._stage(stacked, packed)
+        return self._stage(rows, packed)
 
     def _count_launch(self, plan: BatchPlan, lpad: int, reqs: list,
-                      stacked: tuple, packed: np.ndarray | Resident,
+                      rows: np.ndarray, packed: np.ndarray | Resident,
                       report: BucketReport, rung: int = 0, attempt: int = 0,
                       track: str | None = None) -> None:
         """Bookkeeping for one DISPATCHED launch (called after the
@@ -1075,8 +1117,10 @@ class GeometryServer:
         self._bump("launches")
         # the folds, and the points unless they are the handle's own
         # buffer (recovery stages a rebuilt one)
-        self._bump("upload_bytes", sum(f.nbytes for f in stacked) + (
-            0 if packed is reqs[0].resident else packed.nbytes))
+        own = packed is reqs[0].resident
+        self._bump("upload_bytes", rows.nbytes + (
+            0 if own else packed.nbytes))
+        self._bump("host_operands", 1 if own else 2)
         report.launches += 1
         trc = obst.active()
         if trc.enabled:
@@ -1163,10 +1207,10 @@ class GeometryServer:
                 psid = trc.begin(
                     "bucket.bind" if plan.instanced else "bucket.pack",
                     track=track, rows=len(reqs), lpad=lpad, q=plan.qformat)
-                stacked, packed = self._assemble(reqs, lpad, plan)
+                rows, packed = self._assemble(reqs, lpad, plan)
                 trc.end(psid)
             else:
-                stacked, packed = self._assemble(reqs, lpad, plan)
+                rows, packed = self._assemble(reqs, lpad, plan)
             chunks = self._chunks(len(reqs), lpad)
             payload = sum(r.n for r in reqs)
             report = BucketReport(
@@ -1179,7 +1223,7 @@ class GeometryServer:
                 launches.append(_Launch(
                     ident=ident, qname=qname, backend=bk, lpad=lpad,
                     plan=plan,
-                    stacked=jax.tree.map(lambda x: x[sl], stacked),
+                    rows=rows[sl],
                     packed=packed if resident is not None else packed[sl],
                     reqs=reqs[sl], report=report, track=track))
             self.last_report.append(report)
@@ -1217,7 +1261,7 @@ class GeometryServer:
         # siblings.
         def _stage_first(L: _Launch):
             try:
-                return self._stage_attempt(L.plan, L.stacked, L.packed,
+                return self._stage_attempt(L.plan, L.rows, L.packed,
                                            L.reqs, 0, 0)
             except Exception as e:       # staging failure is a launch failure
                 return _FailedLaunch(e)
@@ -1232,7 +1276,7 @@ class GeometryServer:
                 if isinstance(staged, _FailedLaunch):
                     raise staged.err
                 self._check_injected(L.reqs, 0, 0)
-                self._count_launch(L.plan, L.lpad, L.reqs, L.stacked,
+                self._count_launch(L.plan, L.lpad, L.reqs, L.rows,
                                    L.packed, L.report, rung=0, attempt=0,
                                    track=L.track)
                 out = self._call(L.plan, staged, L.track)       # set 0
@@ -1405,16 +1449,16 @@ class GeometryServer:
                                  rung=rung, attempt=attempt) \
                     if trc.enabled else None
                 try:
-                    stacked, packed = self._assemble(reqs, L.lpad, plan)
+                    rows, packed = self._assemble(reqs, L.lpad, plan)
                     if plan.instanced:
                         # rebuild the buffer from the host snapshot: the
                         # device copy may be what failed
                         packed = Resident(packed.host,
                                           _place_resident(packed.host))
-                    dev = self._stage_attempt(plan, stacked, packed, reqs,
+                    dev = self._stage_attempt(plan, rows, packed, reqs,
                                               ri, attempt)
                     self._check_injected(reqs, ri, attempt)
-                    self._count_launch(plan, L.lpad, reqs, stacked, packed,
+                    self._count_launch(plan, L.lpad, reqs, rows, packed,
                                        L.report, rung=ri, attempt=attempt,
                                        track=rtrack)
                     out = self._call(plan, dev, rtrack)

@@ -583,6 +583,94 @@ class TestEngineTracing:
                                  size_class=lpad) == 1
 
 
+#: (dim, template, lane) of an array bucket of each plan kind and lane
+_ARRAY_BUCKETS = [(2, "TST", None), (3, "SAT", "q8.7"), (3, "TRS", None),
+                  (2, "TSRT", "q8.7"), (3, "TSRP", None)]
+
+
+def _bucket_id(case):
+    dim, letters, lane = case
+    return f"{dim}D-{letters}-{lane or 'f32'}"
+
+
+class TestHostOperands:
+    """``host_operands``: the host arrays each dispatched launch stages,
+    one fold row and, off a handle, the packed points."""
+
+    @pytest.mark.parametrize("case", _ARRAY_BUCKETS, ids=_bucket_id)
+    def test_an_array_launch_stages_two_host_arrays(self, case):
+        dim, letters, lane = case
+        rng = np.random.default_rng(81)
+        srv = _fresh(backend="ref")
+        for n in (5, 40, 40, 200):
+            srv.submit(workload.chain_for(rng, dim, letters),
+                       rng.uniform(-1, 1, (n, dim)).astype(np.float32),
+                       qformat=lane)
+        srv.flush()
+        assert serving.stats["launches"] == 3
+        assert serving.stats["host_operands"] == \
+            srv.metrics.value("host_operands") == 2 * 3
+
+    @pytest.mark.parametrize("case", _ARRAY_BUCKETS, ids=_bucket_id)
+    def test_upload_bytes_are_each_launchs_fold_rows_and_points(self, case):
+        """The bytes staged are what they were with the folds stacked
+        part by part: each request's fold words and its padded
+        points."""
+        from repro import quantize
+        dim, letters, lane = case
+        rng = np.random.default_rng(82)
+        chains = [workload.chain_for(rng, dim, letters) for _ in range(3)]
+        srv = _fresh(backend="ref")
+        for c in chains:
+            srv.submit(c, rng.uniform(-1, 1, (20, dim)).astype(np.float32),
+                       qformat=lane)
+        srv.flush()
+        (rep,) = srv.last_report
+        folds = [c.fold() for c in chains]
+        if lane is not None:
+            folds = [quantize.quantize_fold(f, rep.kind, lane)
+                     for f in folds]
+        itemsize = 2 if lane else 4
+        assert serving.stats["upload_bytes"] == sum(
+            part.nbytes for f in folds for part in f) + \
+            3 * rep.lpad * dim * itemsize
+
+    def test_a_resident_launch_stages_one_host_array(self):
+        from repro import graphics
+        cam = graphics.Camera(eye=(0.0, 0.5, 4.0), target=(0.0, 0.0, 0.0),
+                              up=(0.0, 1.0, 0.0), fov_y=0.8, aspect=1.5,
+                              near=0.1, far=10.0)
+        chains = [graphics.viewing_chain(
+            3, model=tc.TransformChain.identity(3).rotate(0.2 * i, axis=1),
+            camera=cam, viewport=graphics.Viewport(width=64.0, height=48.0))
+            for i in range(6)]
+        srv = _fresh(backend="ref")
+        handle = srv.upload(_pts(300, 3))
+        # three instances a launch: two launches on one handle
+        srv.max_points_per_launch = 3 * handle.lpad
+        srv.serve([(c, handle) for c in chains])
+        assert serving.stats["launches"] == 2
+        assert serving.stats["host_operands"] == serving.stats["launches"]
+        assert serving.stats["upload_bytes"] == 88 * 6
+
+    def test_recovery_launches_are_counted(self):
+        """A launch that recovery re-stages counts its host arrays as
+        any other: blocked attempts stage nothing, retried, bisected and
+        fallen-back launches two each."""
+        inj = faults.FaultInjector(flaky_tickets=frozenset({0}),
+                                   flaky_attempts=2,
+                                   poison_tickets=frozenset({3}))
+        srv = _fresh(backend="ref", fault_config=_cfg(max_launch_attempts=2),
+                     injector=inj)
+        results = srv.serve([(_chain2(), _pts(8)) for _ in range(6)])
+        assert isinstance(results[3], serving.LaunchError)
+        assert serving.stats["retries"] > 0 and \
+            serving.stats["bisections"] > 0
+        assert serving.stats["launches"] > serving.stats["prefetches"] == 0
+        assert serving.stats["host_operands"] == \
+            2 * serving.stats["launches"]
+
+
 class TestSpanTreesUnderFaults:
     def _traced_faulty(self, inj, n=6, **srv_kw):
         srv = _fresh(backend="ref", fault_config=_cfg(max_launch_attempts=2),

@@ -411,6 +411,162 @@ def test_two_structures_of_one_plan_identity_share_one_bucket():
 
 
 # ---------------------------------------------------------------------------
+# one fold row a launch: the folds travel as one (B, words) array
+# ---------------------------------------------------------------------------
+
+#: (dim, template) of each plan kind, and the lanes it has
+_ROW_CASES = [(dim, kind, lane)
+              for dim in (2, 3)
+              for kind in ("diag", "matrix", "projective")
+              for lane in (None, "q8.7")
+              if not (lane and kind == "projective")]
+_TEMPLATE = {(2, "diag"): "TST", (3, "diag"): "SAT",
+             (2, "matrix"): "TSRT", (3, "matrix"): "TRS",
+             (2, "projective"): "TSP", (3, "projective"): "TSRP"}
+
+
+def _row_requests(dim, kind, seed, sizes=(7, 30, 30, 120)):
+    """Requests of one plan identity at three size classes: buckets of
+    one row and of two."""
+    rng = np.random.default_rng(seed)
+    return [(workload.chain_for(rng, dim, _TEMPLATE[dim, kind]),
+             rng.uniform(-1, 1, (n, dim)).astype(np.float32))
+            for n in sizes]
+
+
+def _case_id(case):
+    dim, kind, lane = case
+    return f"{dim}D-{kind}-{lane or 'f32'}"
+
+
+@pytest.mark.parametrize("case", _ROW_CASES, ids=_case_id)
+def test_a_fold_row_splits_back_into_each_part_bit_for_bit(case):
+    """``_fold_row`` lays each request's fold parts side by side in one
+    row of the lane's dtype, and the plan's ``_fold_parts`` takes them
+    apart on the device into exactly the arrays the parts stacked one by
+    one make: every bit, infinite cull bounds included."""
+    import jax
+    from repro import quantize
+    dim, kind, lane = case
+    folds = [c.fold() for c, _ in _row_requests(dim, kind, 71)]
+    dtype = np.float32
+    if lane is not None:
+        folds = [quantize.quantize_fold(f, kind, lane) for f in folds]
+        dtype = np.int16
+    rows = engine._fold_row(folds, dtype)
+    assert rows.dtype == dtype and rows.shape == (
+        len(folds), sum(part.size for part in folds[0]))
+    parts = jax.jit(lambda r: engine._fold_parts(r, kind, dim))(rows)
+    for got, want in zip(parts, zip(*folds), strict=True):
+        want = np.stack(want)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.asarray(got).tobytes() == want.tobytes()
+
+
+def _assert_served_is_apply(chain, pts, lane, out):
+    """``out`` is ``chain``'s own ``apply`` (``project``, with its cull
+    mask) of ``pts`` bit for bit; a float diagonal plan to the
+    one-rounding bound of ``assert_diag_within_fma``, since XLA:CPU
+    contracts ``p*s + t`` into a fused multiply-add in one program shape
+    and not in another, with folds staged either way."""
+    if chain.is_projective:
+        exp, mask = chain.project(jnp.asarray(pts), backend="ref")
+        assert np.array_equal(out.mask, np.asarray(mask))
+    else:
+        exp = chain.apply(jnp.asarray(pts), backend="ref", dtype=lane)
+    exp = np.asarray(exp)
+    assert out.dtype == exp.dtype and out.shape == exp.shape
+    if chain.is_diagonal and lane is None:
+        assert_diag_within_fma(out, exp, chain, pts)
+    else:
+        assert out.tobytes() == exp.tobytes()
+
+
+@pytest.mark.parametrize("case", _ROW_CASES, ids=_case_id)
+def test_a_flush_of_fold_rows_is_bitwise_per_request_apply(case):
+    """Each request served through a flush, whose launches stage one
+    fold row and the points, equals its chain's own ``apply`` (or
+    ``project``, with its cull mask) bit for bit, on one-row and
+    two-row buckets, on the float and the q8.7 lane."""
+    dim, kind, lane = case
+    reqs = _row_requests(dim, kind, 72)
+    srv = _fresh_server(backend="ref")
+    outs = srv.serve(reqs, qformat=lane)
+    assert serving.stats["launches"] == 3
+    assert serving.stats["host_operands"] == 2 * serving.stats["launches"]
+    for (chain, pts), out in zip(reqs, outs, strict=True):
+        _assert_served_is_apply(chain, pts, lane, out)
+
+
+_MESH_ROWS = """
+    import json
+    import jax, numpy as np
+    from repro import serving
+    from repro.launch.mesh import make_mesh
+    from repro.serving import workload
+    cases = json.loads(sys.argv[1])
+    rng = np.random.default_rng(73)
+    reqs = [(workload.chain_for(rng, dim, letters),
+             rng.uniform(-1, 1, (n, dim)).astype(np.float32), lane)
+            for dim, letters, lane in cases for n in (7, 30, 30, 120, 30)]
+
+    def serve():
+        srv = serving.GeometryServer(backend="ref")
+        for chain, pts, lane in reqs:
+            srv.submit(chain, pts, qformat=lane)
+        return srv.flush()
+    one = serve()
+    serving.reset_stats()
+    with jax.set_mesh(make_mesh((4,), ("data",))):
+        mesh = serve()
+    saved = {}
+    for i, (a, b) in enumerate(zip(one, mesh, strict=True)):
+        saved.update({f"one{i}": a, f"mesh{i}": b})
+        if getattr(a, "mask", None) is not None:
+            saved.update({f"one{i}.mask": a.mask, f"mesh{i}.mask": b.mask})
+    np.savez(sys.argv[2], **saved)
+    print(json.dumps({"launches": serving.stats["launches"],
+                      "host_operands": serving.stats["host_operands"]}))
+"""
+
+
+def test_a_mesh_flush_of_fold_rows_is_bitwise_per_request_apply(tmp_path):
+    """Under ``jax.set_mesh`` on 4 virtual CPU devices ``_stage`` shards
+    each launch's one fold row on the batch axis with its points: every
+    plan kind, dimension and lane returns one device's results bit for
+    bit, and each equals per-request ``apply`` as on one device (a
+    child process, so the device-count flag stays out of this one)."""
+    cases = [(dim, _TEMPLATE[dim, kind], lane)
+             for dim, kind, lane in _ROW_CASES]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    script = "import sys\n" + textwrap.dedent(_MESH_ROWS)
+    saved = tmp_path / "outs.npz"
+    out = subprocess.run(
+        [sys.executable, "-c", script, json.dumps(cases), str(saved)],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    # three size classes for each of the 10 plan identities and lanes
+    assert rec["launches"] == 3 * len(_ROW_CASES)
+    assert rec["host_operands"] == 2 * rec["launches"]
+    arrays = np.load(saved)
+    rng = np.random.default_rng(73)
+    reqs = [(workload.chain_for(rng, dim, letters),
+             rng.uniform(-1, 1, (n, dim)).astype(np.float32), lane)
+            for dim, letters, lane in cases for n in (7, 30, 30, 120, 30)]
+    for i, (chain, pts, lane) in enumerate(reqs):
+        one, mesh = arrays[f"one{i}"], arrays[f"mesh{i}"]
+        assert one.dtype == mesh.dtype and one.tobytes() == mesh.tobytes()
+        if chain.is_projective:
+            mask = arrays[f"mesh{i}.mask"]
+            assert np.array_equal(arrays[f"one{i}.mask"], mask)
+            mesh = engine._projected(mesh, mask)
+        _assert_served_is_apply(chain, pts, lane, mesh)
+
+
+# ---------------------------------------------------------------------------
 # sharding oversized buckets
 # ---------------------------------------------------------------------------
 

@@ -11,6 +11,7 @@ loads the TPU compiler, and a host that cannot describe it skips.
 """
 import functools
 import importlib
+import math
 import os
 
 import jax
@@ -99,4 +100,38 @@ def test_instanced_kernel_compiles_for_v5e(vertices, d, one_chip):
     specs = [jax.ShapeDtypeStruct(s, F32, sharding=one_chip) for s in shapes]
     compiled = jax.jit(_project.chain_project_instanced_2d).lower(
         *specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+#: each batch plan the server builds: (dim, plan kind, q-format)
+PLANS = [(d, kind, q) for d in (2, 3)
+         for kind in ("diag", "matrix", "projective")
+         for q in (None, "q8.7") if not (q and kind == "projective")]
+
+
+@pytest.mark.parametrize("d,kind,qname", PLANS)
+def test_served_plan_compiles_for_v5e(d, kind, qname, one_chip):
+    """A served plan as a launch calls it: one fold row and the packed
+    points, the row split into the kernel's operands on the device."""
+    from repro.serving import engine
+    plan = engine._compile_batch_q((d, kind), "pallas", qname) \
+        if qname else engine._compile_batch((d, kind), "pallas")
+    dt = I16 if qname else F32
+    words = sum(math.prod(s) for s in engine._fold_shapes(kind, d))
+    rows = jax.ShapeDtypeStruct((B, words), dt, sharding=one_chip)
+    pts = jax.ShapeDtypeStruct((B, L, d), dt, sharding=one_chip)
+    compiled = plan.fn.lower(rows, pts).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_served_instanced_plan_compiles_for_v5e(one_chip):
+    """The resident Bunny's plan: 8 fold rows over its buffer."""
+    from repro.kernels import util
+    from repro.serving import engine
+    plan = engine._compile_batch((3, "projective"), "pallas",
+                                 instanced=True)
+    buffer = (util.resident_rows(35_947 * 3, 3), util.lane_group(3))
+    rows = jax.ShapeDtypeStruct((8, 22), F32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(buffer, F32, sharding=one_chip)
+    compiled = plan.fn.lower(rows, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
